@@ -45,6 +45,9 @@ def main() -> None:
                          "disturbed")
     args = ap.parse_args()
 
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     results = {}
     lines = []
 

@@ -11,6 +11,9 @@ from repro.core import make_scenario
 from repro.data import make_mnist_like
 from repro.fl import train_federated
 from repro.runtime import ElasticReassociator, FailureInjector
+from repro.utils.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 N, K = 20, 4
 

@@ -21,6 +21,9 @@ import numpy as np
 from repro.core.scenario import make_large_scenario
 from repro.data import make_mnist_like
 from repro.fl import run_live
+from repro.utils.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 N, K, ROUNDS = 40, 4, 6
 sc = make_large_scenario(N, K, seed=0)

@@ -12,6 +12,9 @@ import numpy as np
 
 from repro.core import make_scenario
 from repro.core.edge_association import AssociationEngine, evaluate_scheme
+from repro.utils.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 N_DEVICES, N_SERVERS = 20, 5
 

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.models import build_model
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -59,4 +60,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -22,6 +22,7 @@ from repro.core import SyncLevel, SyncSchedule
 from repro.data import TokenPipeline
 from repro.models import build_model
 from repro.optim import adamw, apply_updates, clip_by_global_norm
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -100,4 +101,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

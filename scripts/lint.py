@@ -6,7 +6,7 @@
     python scripts/lint.py --check src/repro/core   # subset of targets
 
 ``--check`` lints the targets (default: src/repro, benchmarks, scripts,
-examples), diffs the findings against ``lint_baseline.json`` at the repo
+examples, chip_smoke.py), diffs the findings against ``lint_baseline.json`` at the repo
 root, and exits non-zero if anything NEW appears. Baselined findings must
 carry an inline ``# hfellint: disable=RULE -- reason`` pragma or a baseline
 entry; ``--fix-baseline`` regenerates the latter from the current state
@@ -30,7 +30,8 @@ from repro.analysis import (diff_against_baseline, lint_paths,  # noqa: E402
                             load_baseline, save_baseline)
 from repro.analysis.baseline import DEFAULT_BASELINE  # noqa: E402
 
-DEFAULT_TARGETS = ["src/repro", "benchmarks", "scripts", "examples"]
+DEFAULT_TARGETS = ["src/repro", "benchmarks", "scripts", "examples",
+                   "chip_smoke.py"]
 
 
 def main(argv: list[str] | None = None) -> int:

@@ -1,8 +1,8 @@
 #!/usr/bin/env bash
 # Tier-1 verification: run the FULL test suite. The seed_known_failure set
-# (tests/conftest.py) is empty since PR 3 fixed the 14 seed-snapshot jax
-# incompatibilities, so that marker filter currently deselects nothing; it
-# stays as plumbing for any future environment-bound straggler.
+# (tests/conftest.py) is empty, so that marker filter deselects nothing. The
+# suite is not fully green on the installed JAX (0.9.0): the three
+# small-mesh tests of tests/test_launch.py fail (see tests/conftest.py).
 #
 #   scripts/tier1.sh            full tier-1 suite (the PR gate)
 #   scripts/tier1.sh --fast     developer loop: deselect the `slow`-marked

@@ -139,8 +139,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core import resource_allocation as ra
@@ -610,7 +609,7 @@ def _sharded_runner(mesh, n_buckets: int, has_warm: bool, *, kind, profile,
     slices are partitioned along :data:`_SHARD_AXIS`; membership, assignment
     and all scalars are replicated, and the returned toggle caches reassemble
     into the global padded layout (so ``rerun_incremental`` warm-starts work
-    unchanged across device counts). ``check_rep=False`` is required: jax
+    unchanged across device counts). ``check_vma=False`` is required: jax
     has no replication rule for ``lax.while_loop`` bodies, and the impl's
     explicit psum/all_gather merges are what keep the replicated outputs
     consistent."""
@@ -631,8 +630,8 @@ def _sharded_runner(mesh, n_buckets: int, has_warm: bool, *, kind, profile,
         in_specs = (rep, rep, rep, shd, rep, rep, shd, shd, rep, rep, rep,
                     warm_spec)
         out_specs = (rep, rep, rep, shd, rep, rep)
-        fn = jax.jit(shard_map(body, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=False))
+        fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                   out_specs=out_specs, check_vma=False))
         _SHARDED_CACHE[key] = fn
     return fn
 
@@ -956,7 +955,11 @@ class FastAssociationEngine:
             grow = np.arange(kb)
             bucket_of[grow // rows_per, srvs] = b
             row_of[grow // rows_per, srvs] = grow % rows_per
-        return tuple(padded), jnp.asarray(bucket_of), jnp.asarray(row_of)
+        # place each shard's rows on its own device once, here, rather than
+        # keeping the whole padded layout on the first device and scattering
+        # it again on every sweep call
+        rows = NamedSharding(self._mesh, P(_SHARD_AXIS))
+        return jax.device_put((tuple(padded), bucket_of, row_of), rows)
 
     def _gather_bucket(self, servers, idx, exists, ok) -> _Bucket:
         """Pre-gather every per-device RA quantity into this bucket's

@@ -303,7 +303,9 @@ def solve_fixed_point_batched(c: RAConstants, masks: jnp.ndarray, *,
       (interpret mode off-TPU): the whole bracket + golden-section + inner
       fixed-point stack runs as one VMEM-resident kernel per group block.
       Matches the XLA path to float32 rounding, not bit-exactly — parity is
-      pinned at rtol 2e-4 on cost (tests/test_assoc_sharded.py).
+      pinned at rtol 2e-4 on cost (tests/test_assoc_sharded.py); a group
+      whose cost along the deadline path is not unimodal can land in
+      another local minimum (see :mod:`repro.kernels.golden_section`).
     """
     if backend == "xla":
         return jax.vmap(

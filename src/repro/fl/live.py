@@ -114,6 +114,15 @@ DEFAULT_CHURN = {"drift_m": 60.0, "move_frac": 0.05, "flip_frac": 0.02,
                  "depart_frac": 0.02, "arrive_frac": 0.10}
 
 
+def churn_tick(sc: Scenario, *, seed: int, r: int, churn: dict | None = None):
+    """Round ``r``'s churn tick of a live run seeded with ``seed``: one
+    :func:`perturb_scenario` step, returning ``(scenario, delta)``. Its seed
+    depends on ``(seed, r)`` alone, so every policy faces the same scenario
+    trajectory, and a caller can replay a run's scenarios round by round."""
+    return perturb_scenario(sc, seed=(seed + 1) * 1_000_003 + r,
+                            **(DEFAULT_CHURN if churn is None else churn))
+
+
 @dataclass
 class LiveHistory:
     """Per-round record of one live co-simulation.
@@ -270,11 +279,6 @@ class LiveHFELRunner:
 
     # -- internals -----------------------------------------------------------
 
-    def _tick_seed(self, r: int) -> int:
-        # deterministic per (seed, round); identical across policies so every
-        # policy sees the exact same churn trajectory
-        return (self.seed + 1) * 1_000_003 + r
-
     def _new_engine(self, sc: Scenario) -> FastAssociationEngine:
         return FastAssociationEngine(sc, kind=self.kind, seed=self.seed,
                                      rel_tol=self.rel_tol,
@@ -391,8 +395,8 @@ class LiveHFELRunner:
         capped = self._admitted is not None
         if capped:
             admitted_before = self._admitted.copy()
-            self._sc_full, delta = perturb_scenario(
-                self._sc_full, seed=self._tick_seed(r), **self.churn)
+            self._sc_full, delta = churn_tick(self._sc_full, seed=self.seed,
+                                              r=r, churn=self.churn)
             full_active = self._sc_full.active_mask
             # true-scenario departures leave the admitted set and the queue;
             # arrivals join the queue — streaming admission is the ONLY path
@@ -402,9 +406,8 @@ class LiveHFELRunner:
             self._queue.extend(np.flatnonzero(delta.arrived).tolist())
             self._rebuild_view()
         else:
-            self.sc, delta = perturb_scenario(self.sc,
-                                              seed=self._tick_seed(r),
-                                              **self.churn)
+            self.sc, delta = churn_tick(self.sc, seed=self.seed, r=r,
+                                        churn=self.churn)
         assoc_s, moves, swapped, admitted_n = 0.0, 0, False, 0
         resolve = self.policy != "static" and r % self.resolve_every == 0
         if resolve and self.policy == "incremental-warm":

@@ -79,7 +79,7 @@ def hier_aggregate_tree(trees: list, weights):
 
 def golden_section_solve(a, b, d, e, w, f_min, f_max, mask, *,
                          n_golden: int = 48, n_inner: int = 12,
-                         n_bracket: int = 60, block_g: int = 256):
+                         n_bracket: int = 60, block_g: int | None = None):
     """Batched fused golden-section RA solve; see
     :mod:`repro.kernels.golden_section` for shapes."""
     return _golden_section_solve(a, b, d, e, w, f_min, f_max, mask,

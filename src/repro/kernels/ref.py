@@ -60,8 +60,10 @@ def golden_section_ref(a, b, d, e, w, f_min, f_max, mask, *,
         return jnp.where(mask, score / tot, 0.0)
 
     def beta_of_f(f):
+        # the kernel's cube root, exp(log(x)/3), op for op
         tau = 2.0 * b * f ** 3 / jnp.maximum(e, eps)
-        return beta_norm(jnp.cbrt(jnp.maximum(a + tau * d, eps)))
+        return beta_norm(jnp.exp(jnp.log(jnp.maximum(a + tau * d, eps))
+                                 / 3.0))
 
     def safe(beta):
         return jnp.where(mask, jnp.maximum(beta, eps), 1.0)

@@ -20,7 +20,7 @@ from repro.core.assoc_fast import assignment_true_cost
 from repro.core.scenario import (device_client_bridge, diff_scenarios,
                                  make_large_scenario, perturb_scenario)
 from repro.data import make_mnist_like
-from repro.fl import run_live
+from repro.fl import churn_tick, run_live
 from repro.fl.live import LiveHFELRunner
 
 N, K = 16, 3
@@ -110,6 +110,23 @@ def test_per_round_cost_matches_standalone_evaluator(sc, ds):
     assert h.system_cost[0] == pytest.approx(c, rel=1e-6)
     assert h.system_energy[0] == pytest.approx(e, rel=1e-6)
     assert h.system_delay[0] == pytest.approx(t, rel=1e-6)
+
+
+def test_churn_tick_replays_the_run_scenarios(sc, ds):
+    """churn_tick depends on (seed, round) alone, so replaying it rebuilds
+    every round's scenario: each swap assignment then checks against the
+    scenario it was solved on (active count, reachability, eq.-17 cost)."""
+    h = _live(sc, ds, "incremental-warm", resolve_every=1, rounds=3)
+    scs = [sc]
+    for r in range(1, h.rounds):
+        scs.append(churn_tick(scs[-1], seed=0, r=r, churn=CHURN)[0])
+    assert h.swap_rounds == [0, 1, 2]
+    for r, assign in zip(h.swap_rounds, h.swap_assignments):
+        act = np.flatnonzero(scs[r].active_mask)
+        assert act.size == h.n_active[r]
+        assert np.asarray(scs[r].eff_avail)[assign[act], act].all()
+        _, _, c = assignment_true_cost(scs[r], assign)
+        assert h.system_cost[r] == pytest.approx(c, rel=1e-6)
 
 
 def test_true_cost_of_fully_departed_population_is_zero(sc):
