@@ -1,0 +1,6 @@
+"""Mean moves the device loop applied per request (``engine.last_moves``)."""
+
+
+def read(ctx):
+    vals = [r["moves"] for r in ctx["records"] if r.get("moves") is not None]
+    return sum(vals) / len(vals) if vals else None
