@@ -3,7 +3,8 @@ an incremental toggle-cost delta cache, parameterised by slot-index maps.
 
 This is the performance engine behind Algorithm 3 / ``run_batched``: the whole
 steepest-descent adjustment loop runs inside ONE jitted ``lax.while_loop``
-with donated state buffers, so a full association run costs a single host
+with donated state buffers, enqueued right behind the program that fills
+the toggle-cost cache, so a full association run costs a single host
 round-trip regardless of how many adjustments it applies. The reference
 :class:`~repro.core.edge_association.AssociationEngine` instead drives every
 round through Python loops, frozenset-keyed memo dicts, and one
@@ -124,10 +125,21 @@ every §V.A scheme kind works here; ``profile`` selects a
 ("default" reproduces the reference engine bit-for-bit on the solve level,
 "screen"/"coarse" cut sweep cost ~2-4x for large-N scenarios).
 
-Compilation: one XLA program per (bucket shape tuple, ``max_moves``,
-``exchange_samples``, ``kind``, ``profile``, ``permission``,
-``min_residual``). The jit cache is module-global, so repeated engines on
-same-shaped scenarios reuse the compiled program.
+Compilation: one cache-init program (:func:`_init_cache`) per (bucket
+shape tuple, ``kind``, ``profile``, cold or warm start) and one move-loop
+program per (bucket shape tuple, ``max_moves``, ``exchange_samples``,
+``kind``, ``profile``, ``permission``, ``min_residual``). The jit caches
+are module-global, so repeated engines on same-shaped scenarios reuse the
+compiled programs.
+
+Tracing (:mod:`repro.utils.tracing`, off by default): host spans
+``hfel.build.{solver,reach,space}``, ``hfel.init_assign``,
+``hfel.sweep.init``, ``hfel.sweep.loop`` (each device span closes once the
+device is done), ``hfel.readback``, ``hfel.finalize``,
+``hfel.rerun.{patch,repair}``, and one ``hfel.count`` event per sweep
+carrying the :data:`COUNT_NAMES` counters. The device code carries the
+``named_scope`` names ``hfel.init``, ``hfel.scan``, ``hfel.exchange``,
+``hfel.refresh`` and ``hfel.solve.{xla,pallas}``.
 """
 
 from __future__ import annotations
@@ -152,6 +164,7 @@ from repro.core.edge_association import (AssociationResult, GroupSolver,
 from repro.core.scenario import (ReachBuckets, ReachIndex, Scenario,
                                  ScenarioDelta, reach_index_map,
                                  update_reach_buckets, update_reach_index)
+from repro.utils import tracing
 
 _INF = jnp.inf
 _I32_BIG = np.iinfo(np.int32).max
@@ -176,6 +189,18 @@ BUCKETED_AUTO_THRESHOLD = 0.25
 #: explicitly for a deterministic transfer-only sweep.
 DEFAULT_EXCHANGE_SAMPLES = 64
 
+#: Work counters of one sweep, in the order of the int32 vector that the
+#: device programs carry; ``FastAssociationEngine.last_counts`` names them.
+#: ``init_groups``: group solves issued at cache init (on a warm start, the
+#: stale rows' only); ``stale_rows``: servers re-priced at init (K when
+#: cold); ``iterations``: move-loop iterations; ``transfers``/``exchanges``:
+#: moves applied of each kind; ``exchange_tries``: iterations that took the
+#: exchange branch; ``loop_groups``: group solves issued by the refreshes
+#: and the exchange pricing. Under sharding the solve counts cover the
+#: padded rows and samples every shard prices.
+COUNT_NAMES = ("init_groups", "stale_rows", "iterations", "transfers",
+               "exchange_tries", "exchanges", "loop_groups")
+
 
 class _Bucket(NamedTuple):
     """One slot-width bucket of the unified sweep: the per-server index maps
@@ -196,8 +221,10 @@ def _bucket_cost_fn(kind, profile, bucket, cloud_const):
 
     def cost(row, mask):
         c = jax.tree.map(lambda x: x[row], bucket.consts)
-        sol = solve_group(kind, c, mask, random_f=bucket.random_f[row],
-                          inv_dist_row=bucket.inv_dist[row], profile=profile)
+        with jax.named_scope("hfel.solve.xla"):
+            sol = solve_group(kind, c, mask, random_f=bucket.random_f[row],
+                              inv_dist_row=bucket.inv_dist[row],
+                              profile=profile)
         return sol.cost + jnp.where(jnp.any(mask),
                                     cloud_const[bucket.servers[row]], 0.0)
 
@@ -215,8 +242,9 @@ def _bucket_costs_fn(kind, profile, bucket, cloud_const, ra_backend):
 
         def costs(rows, masks):
             cb = jax.tree.map(lambda x: x[rows], bucket.consts)
-            sol = ra.solve_fixed_point_batched(cb, masks, backend="pallas",
-                                               **iters)
+            with jax.named_scope("hfel.solve.pallas"):
+                sol = ra.solve_fixed_point_batched(cb, masks,
+                                                   backend="pallas", **iters)
             return sol.cost + jnp.where(jnp.any(masks, axis=-1),
                                         cloud_const[bucket.servers[rows]],
                                         0.0)
@@ -225,16 +253,124 @@ def _bucket_costs_fn(kind, profile, bucket, cloud_const, ra_backend):
     return jax.vmap(_bucket_cost_fn(kind, profile, bucket, cloud_const))
 
 
-@partial(jax.jit, donate_argnums=(0, 1),
+def _merge_sum(x, axis):
+    """Re-replicate disjoint single-owner contributions (every non-owner
+    shard contributes exact 0.0, so the psum is bitwise the owner's value);
+    identity on the single-device path."""
+    return lax.psum(x, axis) if axis is not None else x
+
+
+def _counts(**vals):
+    """An int32 work-counter vector (:data:`COUNT_NAMES` order) with the
+    named entries set and the others 0."""
+    return jnp.stack([jnp.asarray(vals.get(name, 0), jnp.int32)
+                      for name in COUNT_NAMES])
+
+
+def _rows_costs_fn(buckets, cloud_const, kind, profile, ra_backend):
+    """``(b, member, rows) -> (m, R_b + 1)``: each bucket-``b`` row's current
+    group cost followed by its R_b single-slot toggle costs, with the
+    membership gathered from the dense mask (padded slots forced False)."""
+    cost_vs = [_bucket_costs_fn(kind, profile, bd, cloud_const, ra_backend)
+               for bd in buckets]
+    eyes = [jnp.eye(bd.idx.shape[1], dtype=bool) for bd in buckets]
+
+    def rows_costs(b, member, rows):
+        bd = buckets[b]
+        rb = bd.idx.shape[1]
+        base = (member[bd.servers[rows][:, None], bd.idx[rows]]
+                & bd.exists[rows])                             # (m, rb)
+        masks = jnp.concatenate(
+            [base[:, None, :], base[:, None, :] ^ eyes[b][None]], axis=1)
+        sids = jnp.repeat(rows, rb + 1)
+        return cost_vs[b](sids, masks.reshape(-1, rb)).reshape(
+            rows.shape[0], rb + 1)
+
+    return rows_costs
+
+
+@partial(jax.jit, donate_argnums=(3,),
+         static_argnames=("kind", "profile", "ra_backend"))
+def _init_cache(member, buckets, cloud_const, kept=None, stale=None, *,
+                kind, profile, ra_backend="xla"):
+    """Fill every bucket's toggle-cost cache: the first of the sweep's two
+    device programs (the move loop, :func:`_run_device`, is the second).
+
+    ``kept`` is ``None`` (cold start: every cache row is solved) or the
+    previous run's cache ``(cur_prev (K,), toggles_prev per bucket)`` with
+    ``stale`` (K,) bool — the incremental-rerun path: rows of non-stale
+    servers are copied from the previous cache and only stale rows pay the
+    R_b+1 group solves, which is what makes re-convergence under small
+    scenario deltas cheap. ``kept`` is donated: its buffers become the new
+    cache.
+
+    Returns ``(cur (K,), toggles per bucket, counts)``; ``counts`` is the
+    :data:`COUNT_NAMES` vector with ``init_groups`` and ``stale_rows`` set.
+    """
+    return _init_cache_impl(member, buckets, cloud_const, kept, stale,
+                            axis=None, kind=kind, profile=profile,
+                            ra_backend=ra_backend)
+
+
+def _init_cache_impl(member, buckets, cloud_const, kept, stale, *, axis,
+                     kind, profile, ra_backend):
+    """Cache-init body shared by :func:`_init_cache` and its ``shard_map``
+    twin (:func:`_sharded_init`), like :func:`_run_device_impl`. Each shard
+    fills its own bucket rows; ``cur`` is re-replicated by ``psum``."""
+    k = member.shape[0]
+    rows_costs = _rows_costs_fn(buckets, cloud_const, kind, profile,
+                                ra_backend)
+    # one server at a time: lax.map keeps peak memory at one server's
+    # (R_b+1, R_b) batch, which is what allows N=2000-scale scenarios on a
+    # single host. On a warm start the per-row cond skips the solves for
+    # rows the delta left valid; the row still flows through the map so
+    # shapes never change.
+    cur = jnp.zeros(k, jnp.float32)
+    toggles = []
+    groups = jnp.asarray(0, jnp.int32)
+    with jax.named_scope("hfel.init"):
+        for b, bd in enumerate(buckets):
+            kb, rb = bd.idx.shape
+            if kept is None:
+                def row_fn(rw, b=b):
+                    return rows_costs(b, member, rw[None])[0]
+                groups = groups + kb * (rb + 1)
+            else:
+                cur_prev, toggles_prev = kept
+
+                def row_fn(rw, b=b):
+                    srv = buckets[b].servers[rw]
+                    old_row = jnp.concatenate([cur_prev[srv][None],
+                                               toggles_prev[b][rw]])
+                    return lax.cond(
+                        stale[srv],
+                        lambda _: rows_costs(b, member, rw[None])[0],
+                        lambda _: old_row, None)
+                # the same (clamped) gather the cond reads: a sharded
+                # padding row is priced with its clamped server's flag
+                groups = groups + jnp.sum(stale[bd.servers],
+                                          dtype=jnp.int32) * (rb + 1)
+            costs = lax.map(row_fn, jnp.arange(kb, dtype=jnp.int32))
+            cur = cur.at[bd.servers].set(costs[:, 0])
+            toggles.append(costs[:, 1:])
+    stale_rows = k if kept is None else jnp.sum(stale, dtype=jnp.int32)
+    return (_merge_sum(cur, axis), tuple(toggles),
+            _counts(init_groups=_merge_sum(groups, axis),
+                    stale_rows=stale_rows))
+
+
+@partial(jax.jit, donate_argnums=(0, 1, 3, 4, 5),
          static_argnames=("kind", "profile", "permission", "min_residual",
                           "max_moves", "exchange_samples", "ra_backend"))
-def _run_device(member, assignment, key, buckets, ex_bucket, slot_of,
-                bucket_of, row_of, cloud_const, cap, rel_tol, warm=None, *,
-                kind, profile, permission, min_residual, max_moves,
-                exchange_samples, ra_backend="xla"):
+def _run_device(member, assignment, key, cur, toggles, counts, buckets,
+                ex_bucket, slot_of, bucket_of, row_of, cloud_const, cap,
+                rel_tol, *, kind, profile, permission, min_residual,
+                max_moves, exchange_samples, ra_backend="xla"):
     """The whole adjustment loop as one device program — the single
     move-selection kernel behind every sweep space (dense / flat compact /
-    bucketed; see module docstring).
+    bucketed; see module docstring). It starts from the toggle-cost cache
+    ``cur``/``toggles`` that :func:`_init_cache` filled, and ``counts``,
+    the counter vector that program returned.
 
     ``buckets`` is a static-length tuple of :class:`_Bucket`; ``slot_of``
     (K, N) maps (server, device) to the device's slot in the server's bucket
@@ -251,29 +387,25 @@ def _run_device(member, assignment, key, buckets, ex_bucket, slot_of,
     ``gsize < N`` always holds and the gate selects exactly the historical
     moves. Traced, not static: toggling caps never recompiles.
 
-    ``warm`` is ``None`` (cold start: every cache row is solved at init) or
-    ``(cur_prev (K,), toggles_prev per bucket, stale (K,) bool)`` — the
-    incremental-rerun path: rows of non-stale servers are copied from the
-    previous run's cache and only stale rows pay the R_b+1 group solves,
-    which is what makes re-convergence under small scenario deltas cheap.
-
-    Returns (member, assignment, cur, toggles, n_moves, trace); ``trace[i]``
-    is the surrogate total after move i (trace[0] = initial total), padded
-    with NaN past ``n_moves``.
+    Returns (member, assignment, cur, toggles, n_moves, trace, counts);
+    ``trace[i]`` is the surrogate total after move i (trace[0] = initial
+    total), padded with NaN past ``n_moves``; ``counts`` adds the loop's
+    counters to the init's.
     """
-    return _run_device_impl(member, assignment, key, buckets, ex_bucket,
-                            slot_of, bucket_of, row_of, cloud_const, cap,
-                            rel_tol, warm, axis=None, kind=kind,
+    return _run_device_impl(member, assignment, key, cur, toggles, counts,
+                            buckets, ex_bucket, slot_of, bucket_of, row_of,
+                            cloud_const, cap, rel_tol, axis=None, kind=kind,
                             profile=profile, permission=permission,
                             min_residual=min_residual, max_moves=max_moves,
                             exchange_samples=exchange_samples,
                             ra_backend=ra_backend)
 
 
-def _run_device_impl(member, assignment, key, buckets, ex_bucket, slot_of,
-                     bucket_of, row_of, cloud_const, cap, rel_tol, warm, *,
-                     axis, axis_size=1, kind, profile, permission,
-                     min_residual, max_moves, exchange_samples, ra_backend):
+def _run_device_impl(member, assignment, key, cur0, toggles0, counts0,
+                     buckets, ex_bucket, slot_of, bucket_of, row_of,
+                     cloud_const, cap, rel_tol, *, axis, axis_size=1, kind,
+                     profile, permission, min_residual, max_moves,
+                     exchange_samples, ra_backend):
     """Adjustment-loop body shared by the single-device jit
     (:func:`_run_device`, ``axis=None`` — traced graph identical to the
     historical kernel, so single-device results stay bit-exact) and the
@@ -297,6 +429,11 @@ def _run_device_impl(member, assignment, key, buckets, ex_bucket, slot_of,
     Sampled exchanges distribute with the same split (module docstring,
     "Sharded sweep"): replicated pair proposal, sample-chunk-partitioned
     candidate pricing, all_gather + (delta, sample index) winner fold.
+
+    The work counters ride the loop state and never feed a value. Group
+    solves are counted on the shard that issues them (padded exchange
+    samples included) and summed over shards at the end; the move and
+    iteration counts are replicated.
     """
     k, n = member.shape
     nb = len(buckets)
@@ -313,62 +450,16 @@ def _run_device_impl(member, assignment, key, buckets, ex_bucket, slot_of,
         row_of = row_of.reshape(-1)
 
     def merge_sum(x):
-        """Re-replicate disjoint single-owner contributions (every non-owner
-        shard contributes exact 0.0, so the psum is bitwise the owner's
-        value); identity on the single-device path."""
-        return lax.psum(x, axis) if axis is not None else x
+        return _merge_sum(x, axis)
 
-    cost_vs = [_bucket_costs_fn(kind, profile, bd, cloud_const, ra_backend)
-               for bd in buckets]
-    eyes = [jnp.eye(bd.idx.shape[1], dtype=bool) for bd in buckets]
+    rows_costs = _rows_costs_fn(buckets, cloud_const, kind, profile,
+                                ra_backend)
     ex_cost_v = _bucket_costs_fn(kind, profile, ex_bucket, cloud_const,
                                  ra_backend)
     r_ex = ex_bucket.idx.shape[1]
-
-    def base_rows(b, member, rows):
-        """Compacted membership of bucket ``b``'s given rows, gathered from
-        the dense mask (padded slots forced False)."""
-        bd = buckets[b]
-        return member[bd.servers[rows][:, None], bd.idx[rows]] & bd.exists[rows]
-
-    def rows_costs(b, member, rows):
-        """Solve each row's current group and all R_b single-slot toggles."""
-        bd = buckets[b]
-        rb = bd.idx.shape[1]
-        base = base_rows(b, member, rows)                      # (m, rb)
-        masks = jnp.concatenate(
-            [base[:, None, :], base[:, None, :] ^ eyes[b][None]], axis=1)
-        sids = jnp.repeat(rows, rb + 1)
-        return cost_vs[b](sids, masks.reshape(-1, rb)).reshape(
-            rows.shape[0], rb + 1)
-
-    # ---- init: fill every bucket's toggle cache, one server at a time ----
-    # (lax.map keeps peak memory at one server's (R_b+1, R_b) batch, which
-    # is what allows N=2000-scale scenarios on a single host. On a warm
-    # start the per-row cond skips the solves for rows the delta left
-    # valid; the row still flows through the map so shapes never change.)
-    cur0 = jnp.zeros(k, jnp.float32)
-    toggles0 = []
-    for b, bd in enumerate(buckets):
-        kb = bd.idx.shape[0]
-        if warm is None:
-            def row_fn(rw, b=b):
-                return rows_costs(b, member, rw[None])[0]
-        else:
-            cur_prev, toggles_prev, stale = warm
-
-            def row_fn(rw, b=b):
-                srv = buckets[b].servers[rw]
-                kept = jnp.concatenate([cur_prev[srv][None],
-                                        toggles_prev[b][rw]])
-                return lax.cond(stale[srv],
-                                lambda _: rows_costs(b, member, rw[None])[0],
-                                lambda _: kept, None)
-        costs = lax.map(row_fn, jnp.arange(kb, dtype=i32))     # (kb, rb+1)
-        cur0 = cur0.at[bd.servers].set(costs[:, 0])
-        toggles0.append(costs[:, 1:])
-    toggles0 = tuple(toggles0)
-    cur0 = merge_sum(cur0)
+    # group solves of one refresh per bucket, and 0 for the no-op branch
+    refresh_groups = jnp.asarray([bd.idx.shape[1] + 1 for bd in buckets]
+                                 + [0], i32)
 
     trace0 = jnp.full(max_moves + 1, jnp.nan, cur0.dtype)
     trace0 = trace0.at[0].set(jnp.sum(cur0))
@@ -396,7 +487,8 @@ def _run_device_impl(member, assignment, key, buckets, ex_bucket, slot_of,
 
     def refresh_server(member, server, applied, cur, toggles):
         """Refresh one touched server's cur + toggle row in its own bucket
-        via lax.switch (extra branch = no-op when the move wasn't applied)."""
+        via lax.switch (extra branch = no-op when the move wasn't applied).
+        Also returns the group solves that refresh issued on this shard."""
 
         def branch(b):
             def go(ops):
@@ -408,70 +500,76 @@ def _run_device_impl(member, assignment, key, buckets, ex_bucket, slot_of,
                               for i, t in enumerate(toggles)))
             return go
 
-        return lax.switch(jnp.where(applied, bucket_of[server], nb),
-                          [branch(b) for b in range(nb)] + [lambda ops: ops],
-                          (cur, toggles))
+        which = jnp.where(applied, bucket_of[server], nb)
+        with jax.named_scope("hfel.refresh"):
+            cur, toggles = lax.switch(
+                which, [branch(b) for b in range(nb)] + [lambda ops: ops],
+                (cur, toggles))
+        return cur, toggles, refresh_groups[which]
 
     def body(state):
-        member, assign, cur, toggles, moves, key, trace, _ = state
+        member, assign, cur, toggles, moves, key, trace, _, work = state
         # -- scan all reachable transfer candidates from the cache (no
         #    solves), one fused scan per bucket, argmins merged globally --
-        cur_src = cur[assign]                                  # (n,)
-        minus = removal_toggle(toggles, assign)                # (n,)
-        minus_delta = minus - cur_src
-        gsize = jnp.sum(member, axis=1)                        # (k,)
-        if permission == "pareto":
-            src_harmless = harmless(minus, cur_src)            # (n,)
-
-        best_delta = jnp.asarray(_INF, cur0.dtype)
-        best_order = jnp.asarray(_I32_BIG, i32)
-        t_dev = jnp.asarray(0, i32)
-        t_dst = jnp.asarray(0, i32)
-        for b, bd in enumerate(buckets):
-            rb = bd.idx.shape[1]
-            dev = bd.idx                                       # (kb, rb)
-            cur_b = cur[bd.servers][:, None]                   # (kb, 1)
-            src = assign[dev]                                  # (kb, rb)
-            delta = minus_delta[dev] + toggles[b] - cur_b
-            scale = jnp.maximum(cur_b + cur_src[dev], 1e-9)
-            # capacity feasibility rides the same per-row mask as the
-            # residual-group rule: a destination at cap admits no inbound
-            # transfer (sentinel-padded rows are already ok=False, and the
-            # clamped cap gather there is harmless)
-            headroom = (gsize[bd.servers] < cap[bd.servers])[:, None]
-            valid = (bd.ok & (src != bd.servers[:, None])
-                     & (gsize[src] > min_residual) & headroom)
-            permitted = valid & (delta < -rel_tol * scale)
+        with jax.named_scope("hfel.scan"):
+            cur_src = cur[assign]                              # (n,)
+            minus = removal_toggle(toggles, assign)            # (n,)
+            minus_delta = minus - cur_src
+            gsize = jnp.sum(member, axis=1)                    # (k,)
             if permission == "pareto":
-                permitted &= harmless(toggles[b], cur_b) & src_harmless[dev]
-            masked = jnp.where(permitted, delta, _INF)
-            bucket_best = jnp.min(masked)
-            # explicit device-major order key reproduces the host reference
-            # engine's argmin tie-breaking (smallest n*K + k among equal
-            # deltas) — globally, across buckets
-            order = dev.astype(i32) * k + bd.servers[:, None].astype(i32)
-            tie = jnp.where(masked == bucket_best, order, _I32_BIG)
-            p = jnp.argmin(tie)
-            b_order = tie.reshape(-1)[p]
-            take = ((bucket_best < best_delta)
-                    | ((bucket_best == best_delta) & (b_order < best_order)))
-            best_delta = jnp.where(take, bucket_best, best_delta)
-            best_order = jnp.where(take, b_order, best_order)
-            t_dev = jnp.where(take, dev.reshape(-1)[p], t_dev)
-            t_dst = jnp.where(take, bd.servers[p // rb], t_dst)
-        if axis is not None:
-            # merge the per-shard winners with the SAME lexicographic
-            # (delta, device-major order) rule the bucket fold above uses,
-            # so the sharded sweep selects the identical global move
-            deltas = lax.all_gather(best_delta, axis)          # (p,)
-            orders = lax.all_gather(best_order, axis)
-            g_delta = jnp.min(deltas)
-            g_tie = jnp.where(deltas == g_delta, orders, _I32_BIG)
-            shard = jnp.argmin(g_tie)
-            best_delta = g_delta
-            best_order = g_tie[shard]
-            t_dev = lax.all_gather(t_dev, axis)[shard]
-            t_dst = lax.all_gather(t_dst, axis)[shard]
+                src_harmless = harmless(minus, cur_src)        # (n,)
+
+            best_delta = jnp.asarray(_INF, cur0.dtype)
+            best_order = jnp.asarray(_I32_BIG, i32)
+            t_dev = jnp.asarray(0, i32)
+            t_dst = jnp.asarray(0, i32)
+            for b, bd in enumerate(buckets):
+                rb = bd.idx.shape[1]
+                dev = bd.idx                                   # (kb, rb)
+                cur_b = cur[bd.servers][:, None]               # (kb, 1)
+                src = assign[dev]                              # (kb, rb)
+                delta = minus_delta[dev] + toggles[b] - cur_b
+                scale = jnp.maximum(cur_b + cur_src[dev], 1e-9)
+                # capacity feasibility rides the same per-row mask as the
+                # residual-group rule: a destination at cap admits no
+                # inbound transfer (sentinel-padded rows are already
+                # ok=False, and the clamped cap gather there is harmless)
+                headroom = (gsize[bd.servers] < cap[bd.servers])[:, None]
+                valid = (bd.ok & (src != bd.servers[:, None])
+                         & (gsize[src] > min_residual) & headroom)
+                permitted = valid & (delta < -rel_tol * scale)
+                if permission == "pareto":
+                    permitted &= (harmless(toggles[b], cur_b)
+                                  & src_harmless[dev])
+                masked = jnp.where(permitted, delta, _INF)
+                bucket_best = jnp.min(masked)
+                # explicit device-major order key reproduces the host
+                # reference engine's argmin tie-breaking (smallest n*K + k
+                # among equal deltas) — globally, across buckets
+                order = dev.astype(i32) * k + bd.servers[:, None].astype(i32)
+                tie = jnp.where(masked == bucket_best, order, _I32_BIG)
+                p = jnp.argmin(tie)
+                b_order = tie.reshape(-1)[p]
+                take = ((bucket_best < best_delta)
+                        | ((bucket_best == best_delta)
+                           & (b_order < best_order)))
+                best_delta = jnp.where(take, bucket_best, best_delta)
+                best_order = jnp.where(take, b_order, best_order)
+                t_dev = jnp.where(take, dev.reshape(-1)[p], t_dev)
+                t_dst = jnp.where(take, bd.servers[p // rb], t_dst)
+            if axis is not None:
+                # merge the per-shard winners with the SAME lexicographic
+                # (delta, device-major order) rule the bucket fold above
+                # uses, so the sharded sweep selects the identical move
+                deltas = lax.all_gather(best_delta, axis)      # (p,)
+                orders = lax.all_gather(best_order, axis)
+                g_delta = jnp.min(deltas)
+                g_tie = jnp.where(deltas == g_delta, orders, _I32_BIG)
+                shard = jnp.argmin(g_tie)
+                best_delta = g_delta
+                best_order = g_tie[shard]
+                t_dev = lax.all_gather(t_dev, axis)[shard]
+                t_dst = lax.all_gather(t_dst, axis)[shard]
         has_transfer = jnp.isfinite(best_delta)
         t_src = assign[t_dev]
 
@@ -566,13 +664,21 @@ def _run_device_impl(member, assignment, key, buckets, ex_bucket, slot_of,
 
         args = (member, assign, key)
         if exchange_samples:
+            def exchange(args):
+                with jax.named_scope("hfel.exchange"):
+                    return do_exchange(args)
+
             applied, rows, member, assign, key = lax.cond(
-                has_transfer, do_transfer, do_exchange, args)
+                has_transfer, do_transfer, exchange, args)
+            tried = ~has_transfer
         else:
             applied, rows, member, assign, key = lax.cond(
                 has_transfer, do_transfer, no_exchange, args)
-        cur, toggles = refresh_server(member, rows[0], applied, cur, toggles)
-        cur, toggles = refresh_server(member, rows[1], applied, cur, toggles)
+            tried = jnp.asarray(False)
+        cur, toggles, g0 = refresh_server(member, rows[0], applied, cur,
+                                          toggles)
+        cur, toggles, g1 = refresh_server(member, rows[1], applied, cur,
+                                          toggles)
         if axis is not None:
             # only the touched servers' owners re-solved their cur entries;
             # re-replicate exactly those two (psum of owner-only values)
@@ -583,57 +689,82 @@ def _run_device_impl(member, assignment, key, buckets, ex_bucket, slot_of,
         moves = moves + applied.astype(i32)
         trace = trace.at[moves].set(
             jnp.where(applied, jnp.sum(cur), trace[moves]))
-        return (member, assign, cur, toggles, moves, key, trace, ~applied)
+        work = work + _counts(
+            iterations=1, transfers=applied & has_transfer,
+            exchange_tries=tried, exchanges=applied & ~has_transfer,
+            loop_groups=g0 + g1 + tried.astype(i32) * (2 * ex_chunk))
+        return (member, assign, cur, toggles, moves, key, trace, ~applied,
+                work)
 
     def cond(state):
-        return (~state[-1]) & (state[4] < max_moves)
+        return (~state[7]) & (state[4] < max_moves)
 
     state = (member, assignment, cur0, toggles0, jnp.asarray(0, i32), key,
-             trace0, jnp.asarray(False))
-    member, assignment, cur, toggles, moves, _, trace, _ = lax.while_loop(
-        cond, body, state)
-    return member, assignment, cur, toggles, moves, trace
+             trace0, jnp.asarray(False), _counts())
+    member, assignment, cur, toggles, moves, _, trace, _, work = \
+        lax.while_loop(cond, body, state)
+    loop_groups = COUNT_NAMES.index("loop_groups")
+    work = work.at[loop_groups].set(merge_sum(work[loop_groups]))
+    return member, assignment, cur, toggles, moves, trace, counts0 + work
 
 
-# jitted shard_map programs keyed on (mesh devices, bucket count, warm
-# presence, statics) — module-global like _run_device's jit cache, so
-# repeated engines on same-shaped scenarios reuse the compiled program
+# jitted shard_map programs of the cache init and the move loop, keyed on
+# (program, mesh devices, bucket count, statics) — module-global like the
+# single-device jit caches, so repeated engines on same-shaped scenarios
+# reuse the compiled programs
 _SHARDED_CACHE: dict = {}
 
 
-def _sharded_runner(mesh, n_buckets: int, has_warm: bool, *, kind, profile,
-                    permission, min_residual, max_moves, exchange_samples,
-                    ra_backend):
-    """The sharded counterpart of :func:`_run_device`: the same impl wrapped
-    in ``shard_map`` over ``mesh``. Bucket rows and the per-shard locator
-    slices are partitioned along :data:`_SHARD_AXIS`; membership, assignment
-    and all scalars are replicated, and the returned toggle caches reassemble
-    into the global padded layout (so ``rerun_incremental`` warm-starts work
-    unchanged across device counts). ``check_vma=False`` is required: jax
-    has no replication rule for ``lax.while_loop`` bodies, and the impl's
-    explicit psum/all_gather merges are what keep the replicated outputs
-    consistent."""
-    key = (tuple(mesh.devices.flat), n_buckets, has_warm, kind, profile,
-           permission, min_residual, max_moves, exchange_samples, ra_backend)
+def _shard_mapped(key, body, mesh, in_specs, out_specs):
     fn = _SHARDED_CACHE.get(key)
     if fn is None:
-        body = partial(_run_device_impl, axis=_SHARD_AXIS,
-                       axis_size=int(mesh.devices.size), kind=kind,
-                       profile=profile, permission=permission,
-                       min_residual=min_residual, max_moves=max_moves,
-                       exchange_samples=exchange_samples,
-                       ra_backend=ra_backend)
-        shd, rep = P(_SHARD_AXIS), P()
-        warm_spec = (rep, shd, rep) if has_warm else rep
-        # (member, assignment, key, buckets, ex_bucket, slot_of, bucket_of,
-        #  row_of, cloud_const, cap, rel_tol, warm)
-        in_specs = (rep, rep, rep, shd, rep, rep, shd, shd, rep, rep, rep,
-                    warm_spec)
-        out_specs = (rep, rep, rep, shd, rep, rep)
+        # check_vma=False: jax has no replication rule for lax.while_loop
+        # bodies, and the impls' explicit psum/all_gather merges are what
+        # keep the replicated outputs consistent
         fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                                    out_specs=out_specs, check_vma=False))
         _SHARDED_CACHE[key] = fn
     return fn
+
+
+def _sharded_init(mesh, n_buckets: int, has_warm: bool, *, kind, profile,
+                  ra_backend):
+    """The sharded counterpart of :func:`_init_cache`: each shard fills its
+    own bucket rows; ``cur`` and the counters come back replicated and the
+    toggle caches in the global padded layout (so ``rerun_incremental``
+    warm-starts work unchanged across device counts)."""
+    shd, rep = P(_SHARD_AXIS), P()
+    body = partial(_init_cache_impl, axis=_SHARD_AXIS, kind=kind,
+                   profile=profile, ra_backend=ra_backend)
+    # (member, buckets, cloud_const, kept, stale)
+    in_specs = (rep, shd, rep, (rep, shd) if has_warm else rep, rep)
+    return _shard_mapped(
+        ("init", tuple(mesh.devices.flat), n_buckets, has_warm, kind,
+         profile, ra_backend),
+        body, mesh, in_specs, (rep, shd, rep))
+
+
+def _sharded_runner(mesh, n_buckets: int, *, kind, profile, permission,
+                    min_residual, max_moves, exchange_samples, ra_backend):
+    """The sharded counterpart of :func:`_run_device`: the same impl wrapped
+    in ``shard_map`` over ``mesh``. Bucket rows, their toggle caches and the
+    per-shard locator slices are partitioned along :data:`_SHARD_AXIS`;
+    membership, assignment and all scalars are replicated."""
+    shd, rep = P(_SHARD_AXIS), P()
+    body = partial(_run_device_impl, axis=_SHARD_AXIS,
+                   axis_size=int(mesh.devices.size), kind=kind,
+                   profile=profile, permission=permission,
+                   min_residual=min_residual, max_moves=max_moves,
+                   exchange_samples=exchange_samples, ra_backend=ra_backend)
+    # (member, assignment, key, cur, toggles, counts, buckets, ex_bucket,
+    #  slot_of, bucket_of, row_of, cloud_const, cap, rel_tol)
+    in_specs = (rep, rep, rep, rep, shd, rep, shd, rep, rep, shd, shd, rep,
+                rep, rep)
+    out_specs = (rep, rep, rep, shd, rep, rep, rep)
+    return _shard_mapped(
+        (tuple(mesh.devices.flat), n_buckets, kind, profile, permission,
+         min_residual, max_moves, exchange_samples, ra_backend),
+        body, mesh, in_specs, out_specs)
 
 
 def _dense_member(assignment: np.ndarray, active: np.ndarray,
@@ -832,52 +963,61 @@ class FastAssociationEngine:
         self.min_residual = min_residual_group
         self.rel_tol = rel_tol
         self.seed = seed
-        self.solver = GroupSolver(sc, kind, seed=seed, profile=profile)
-        # final reporting always happens at reference accuracy so costs are
-        # comparable across screening profiles (the sweep may run coarser)
-        self._eval_solver = self.solver.with_profile("default")
-        self.rng = np.random.default_rng(seed)
-        self._active = sc.active_mask
-        self.avail = np.asarray(sc.eff_avail)
-        # per-edge admission caps (None = the paper's uncapacitated model).
-        # The kernel always takes a traced (K,) cap array; uncapped engines
-        # pass N — never binding, since an inbound transfer needs a donor
-        # group elsewhere — so toggling caps changes no jit signature and
-        # the uncapped graph stays bit-identical to the historical one.
-        self.cap = sc.capacity
-        self._cap = jnp.asarray(
-            np.full(sc.n_servers, sc.n_devices, np.int64)
-            if self.cap is None else self.cap, jnp.int32)
-        self.cloud_const = jnp.asarray(
-            np.asarray(sc.lp.lambda_e * cloud_energy(sc.srv)
-                       + sc.lp.lambda_t * cloud_delay(sc.srv),
-                       dtype=np.float32))
+        with tracing.span("hfel.build.solver"):
+            self.solver = GroupSolver(sc, kind, seed=seed, profile=profile)
+            # final reporting always happens at reference accuracy so costs
+            # are comparable across screening profiles (the sweep may run
+            # coarser)
+            self._eval_solver = self.solver.with_profile("default")
+            self.rng = np.random.default_rng(seed)
+            self._active = sc.active_mask
+            self.avail = np.asarray(sc.eff_avail)
+            # per-edge admission caps (None = the paper's uncapacitated
+            # model). The kernel always takes a traced (K,) cap array;
+            # uncapped engines pass N — never binding, since an inbound
+            # transfer needs a donor group elsewhere — so toggling caps
+            # changes no jit signature and the uncapped graph stays
+            # bit-identical to the historical one.
+            self.cap = sc.capacity
+            self._cap = jnp.asarray(
+                np.full(sc.n_servers, sc.n_devices, np.int64)
+                if self.cap is None else self.cap, jnp.int32)
+            self.cloud_const = jnp.asarray(
+                np.asarray(sc.lp.lambda_e * cloud_energy(sc.srv)
+                           + sc.lp.lambda_t * cloud_delay(sc.srv),
+                           dtype=np.float32))
         self.reach: ReachIndex | None = None
         self.reach_buckets: ReachBuckets | None = None
-        try:
-            self.reach = reach_index_map(np.asarray(sc.avail),
-                                         active=self._active)
-        except ValueError:
-            if compact in (True, "bucketed"):
-                raise
-        if compact == "auto":
-            if self.reach is None or self.reach.r_max >= sc.n_devices:
-                compact = False
-            else:
-                # sparse reach -> compact; heavily padded flat maps (skewed
-                # reach counts) -> the bucketed adaptive-width sweep
-                compact = ("bucketed"
-                           if (self.reach.padded_fraction
-                               > BUCKETED_AUTO_THRESHOLD)
-                           else True)
-        self.compact = "bucketed" if compact == "bucketed" else bool(compact)
-        if self.compact == "bucketed":
-            self.reach_buckets = reach_index_map(
-                np.asarray(sc.avail), bucketed=True, active=self._active)
-        self._rebuild_space()
+        with tracing.span("hfel.build.reach"):
+            try:
+                self.reach = reach_index_map(np.asarray(sc.avail),
+                                             active=self._active)
+            except ValueError:
+                if compact in (True, "bucketed"):
+                    raise
+            if compact == "auto":
+                if self.reach is None or self.reach.r_max >= sc.n_devices:
+                    compact = False
+                else:
+                    # sparse reach -> compact; heavily padded flat maps
+                    # (skewed reach counts) -> the bucketed adaptive-width
+                    # sweep
+                    compact = ("bucketed"
+                               if (self.reach.padded_fraction
+                                   > BUCKETED_AUTO_THRESHOLD)
+                               else True)
+            self.compact = ("bucketed" if compact == "bucketed"
+                            else bool(compact))
+            if self.compact == "bucketed":
+                self.reach_buckets = reach_index_map(
+                    np.asarray(sc.avail), bucketed=True, active=self._active)
+        with tracing.span("hfel.build.space"):
+            self._rebuild_space()
         self.last_state: dict | None = None   # debug: cur/toggle cache dump
         self.last_tier_moves: list[int] | None = None
         self.last_moves: int | None = None    # applied moves of the last sweep
+        # the last sweep's work counters, by COUNT_NAMES
+        self.last_counts: dict[str, int] | None = None
         self._warm_cache: dict | None = None  # rerun_incremental state
         self.last_repaired_assignment: np.ndarray | None = None
 
@@ -1011,8 +1151,9 @@ class FastAssociationEngine:
         be timed symmetrically, with cost accounting on the caller's
         schedule.
         """
-        assignment = (self.initial_assignment(init) if assignment is None
-                      else np.asarray(assignment))
+        with tracing.span("hfel.init_assign"):
+            assignment = (self.initial_assignment(init) if assignment is None
+                          else np.asarray(assignment))
         assignment, member, moves, trace = self._sweep(
             assignment, self.profile, max_moves, exchange_samples,
             jax.random.PRNGKey(self.seed))
@@ -1050,8 +1191,9 @@ class FastAssociationEngine:
             raise ValueError(
                 f"tier_rel_tols has {len(rel_tols)} entries for "
                 f"{len(profiles)} tiers")
-        assignment = (self.initial_assignment(init) if assignment is None
-                      else np.asarray(assignment))
+        with tracing.span("hfel.init_assign"):
+            assignment = (self.initial_assignment(init) if assignment is None
+                          else np.asarray(assignment))
         base_key = jax.random.PRNGKey(self.seed)
         total_moves = 0
         trace: list[float] = []
@@ -1132,64 +1274,66 @@ class FastAssociationEngine:
                 "rerun_incremental requires churn-invariant max_devices; "
                 "rebuild the engine to change capacities")
 
-        # ---- swap the scenario and patch the static index maps ----
-        self.sc = sc_new
-        self._active = sc_new.active_mask.copy()
-        self.avail = np.asarray(sc_new.eff_avail)
-        if delta.moved.any():
-            # distance-derived solver buffers (only the "proportional"
-            # scheme reads them; RA constants are delta-invariant)
-            inv = 1.0 / np.maximum(np.asarray(sc_new.dist), 1.0)
-            self.solver.inv_dist = jnp.asarray(inv.astype(np.float32))
-            self._eval_solver = self.solver.with_profile("default")
-        raw = np.asarray(sc_new.avail)
-        stale = np.asarray(delta.stale_servers, dtype=bool).copy()
-        carry: list = [0] * len(self._buckets)
-        if self.compact:
-            # the flat map backs the flat sweep AND the bucketed mode's
-            # shared exchange slot space; dense engines never read it after
-            # __init__'s auto decision, so it is dropped rather than left
-            # silently stale
-            self.reach, flat_rebuilt = update_reach_index(
-                self.reach, raw, active=self._active,
-                changed_servers=delta.stale_servers)
-        else:
-            self.reach = None
-        if self.compact == "bucketed":
-            self.reach_buckets, carry = update_reach_buckets(
-                self.reach_buckets, raw, active=self._active,
-                changed_servers=delta.stale_servers)
-        elif self.compact:
-            carry = [None] if flat_rebuilt else [0]
-        elif self.kind == "proportional" and delta.moved.any():
-            # dense toggle rows span every device, so a moved device's
-            # inv_dist change can touch any row's cached cost
-            stale[:] = True
-        self._rebuild_space()
-
-        # ---- repair the previous stable assignment on the host ----
-        assign, departed, arrived, displaced = repair_assignment(
-            sc_new, prev_assign, old_active)
-        # groups losing a member (departures + displaced previous members)
-        stale[prev_assign[departed]] = True
-        stale[prev_assign[displaced & old_active]] = True
-        # groups gaining a member (every arrival joins *some* group)
-        stale[assign[displaced]] = True
-        stale[assign[arrived]] = True
-
-        # ---- align cached toggle rows to the (possibly patched) layout ----
-        toggles_warm = []
-        for b, bd in enumerate(self._buckets):
-            shape = tuple(bd.idx.shape)
-            src = carry[b] if b < len(carry) else None
-            if src is None or cache["toggles"][src].shape != shape:
-                toggles_warm.append(jnp.zeros(shape, jnp.float32))
-                srvs = np.asarray(bd.servers)
-                stale[srvs[srvs < k]] = True   # skip sharded padding rows
+        with tracing.span("hfel.rerun.patch"):
+            # ---- swap the scenario and patch the static index maps ----
+            self.sc = sc_new
+            self._active = sc_new.active_mask.copy()
+            self.avail = np.asarray(sc_new.eff_avail)
+            if delta.moved.any():
+                # distance-derived solver buffers (only the "proportional"
+                # scheme reads them; RA constants are delta-invariant)
+                inv = 1.0 / np.maximum(np.asarray(sc_new.dist), 1.0)
+                self.solver.inv_dist = jnp.asarray(inv.astype(np.float32))
+                self._eval_solver = self.solver.with_profile("default")
+            raw = np.asarray(sc_new.avail)
+            stale = np.asarray(delta.stale_servers, dtype=bool).copy()
+            carry: list = [0] * len(self._buckets)
+            if self.compact:
+                # the flat map backs the flat sweep AND the bucketed mode's
+                # shared exchange slot space; dense engines never read it after
+                # __init__'s auto decision, so it is dropped rather than left
+                # silently stale
+                self.reach, flat_rebuilt = update_reach_index(
+                    self.reach, raw, active=self._active,
+                    changed_servers=delta.stale_servers)
             else:
-                toggles_warm.append(jnp.asarray(cache["toggles"][src]))
-        warm = (jnp.asarray(cache["cur"]), tuple(toggles_warm),
-                jnp.asarray(stale))
+                self.reach = None
+            if self.compact == "bucketed":
+                self.reach_buckets, carry = update_reach_buckets(
+                    self.reach_buckets, raw, active=self._active,
+                    changed_servers=delta.stale_servers)
+            elif self.compact:
+                carry = [None] if flat_rebuilt else [0]
+            elif self.kind == "proportional" and delta.moved.any():
+                # dense toggle rows span every device, so a moved device's
+                # inv_dist change can touch any row's cached cost
+                stale[:] = True
+            self._rebuild_space()
+
+        with tracing.span("hfel.rerun.repair"):
+            # ---- repair the previous stable assignment on the host ----
+            assign, departed, arrived, displaced = repair_assignment(
+                sc_new, prev_assign, old_active)
+            # groups losing a member (departures + displaced previous members)
+            stale[prev_assign[departed]] = True
+            stale[prev_assign[displaced & old_active]] = True
+            # groups gaining a member (every arrival joins *some* group)
+            stale[assign[displaced]] = True
+            stale[assign[arrived]] = True
+
+            # ---- align cached toggle rows to the patched layout ----
+            toggles_warm = []
+            for b, bd in enumerate(self._buckets):
+                shape = tuple(bd.idx.shape)
+                src = carry[b] if b < len(carry) else None
+                if src is None or cache["toggles"][src].shape != shape:
+                    toggles_warm.append(jnp.zeros(shape, jnp.float32))
+                    srvs = np.asarray(bd.servers)
+                    stale[srvs[srvs < k]] = True   # skip sharded padding rows
+                else:
+                    toggles_warm.append(jnp.asarray(cache["toggles"][src]))
+            warm = (jnp.asarray(cache["cur"]), tuple(toggles_warm),
+                    jnp.asarray(stale))
 
         self.last_repaired_assignment = assign.copy()
         assignment, member, moves, trace = self._sweep(
@@ -1232,11 +1376,11 @@ class FastAssociationEngine:
                exchange_samples: int, key, rel_tol: float | None = None,
                warm=None):
         """One profile's adjustment loop; returns (assignment, dense member,
-        n_moves, trace) and stashes the cache dump in ``last_state``."""
+        n_moves, trace), stashes the cache dump in ``last_state`` and the
+        work counters in ``last_counts``."""
         rel_tol = self.rel_tol if rel_tol is None else rel_tol
         assignment = np.asarray(assignment)
         n, k = self.sc.n_devices, self.sc.n_servers
-        member0 = self._member_of(assignment)
         if self.compact:
             # an out-of-reach assignment has no slot in compacted space: the
             # device would silently vanish from its group and the sweep's
@@ -1262,72 +1406,90 @@ class FastAssociationEngine:
                     f"{over.tolist()[:8]} (load "
                     f"{load[over].tolist()[:8]} > cap "
                     f"{self.cap[over].tolist()[:8]})")
-        args = (jnp.asarray(member0), jnp.asarray(assignment, jnp.int32), key,
-                self._buckets, self._ex_bucket, self._slot_of,
-                self._bucket_of, self._row_of, self.cloud_const, self._cap,
-                jnp.float32(rel_tol), warm)
+        with tracing.span("hfel.init_assign"):
+            member0 = jnp.asarray(self._member_of(assignment))
+            assign0 = jnp.asarray(assignment, jnp.int32)
         if self._mesh is None:
-            member, assign, cur, toggles, moves, trace = _run_device(
-                *args, kind=self.kind,
+            init = partial(_init_cache, kind=self.kind, profile=profile,
+                           ra_backend=self.ra_backend)
+            loop = partial(_run_device, kind=self.kind, profile=profile,
+                           permission=self.permission,
+                           min_residual=self.min_residual,
+                           max_moves=max_moves,
+                           exchange_samples=exchange_samples,
+                           ra_backend=self.ra_backend)
+        else:
+            init = _sharded_init(self._mesh, len(self._buckets),
+                                 warm is not None, kind=self.kind,
+                                 profile=profile, ra_backend=self.ra_backend)
+            loop = _sharded_runner(
+                self._mesh, len(self._buckets), kind=self.kind,
                 profile=profile, permission=self.permission,
                 min_residual=self.min_residual, max_moves=max_moves,
                 exchange_samples=exchange_samples,
                 ra_backend=self.ra_backend)
-        else:
-            runner = _sharded_runner(
-                self._mesh, len(self._buckets), warm is not None,
-                kind=self.kind, profile=profile, permission=self.permission,
-                min_residual=self.min_residual, max_moves=max_moves,
-                exchange_samples=exchange_samples,
-                ra_backend=self.ra_backend)
-            member, assign, cur, toggles, moves, trace = runner(*args)
-        member_np = np.asarray(member)
-        self.last_state = {"member": member_np,
-                           "cur_cost": np.asarray(cur)}
-        if self.compact == "bucketed":
-            self.last_state.update(
-                toggle_cost_buckets=[np.asarray(t) for t in toggles],
-                reach_buckets=self.reach_buckets)
-        elif self.compact:
-            r = self.reach
-            self.last_state.update(
-                member_compact=(member_np[np.arange(k)[:, None], r.idx]
-                                & r.valid),
-                toggle_cost_compact=np.asarray(toggles[0]),
-                reach=r)
-        else:
-            self.last_state.update(toggle_cost=np.asarray(toggles[0]))
-        moves = int(moves)
-        self.last_moves = moves
-        trace = [float(x) for x in np.asarray(trace[:moves + 1], np.float64)]
-        assign_np = np.asarray(assign, np.int64)
-        # stable-point cache for rerun_incremental: everything a warm start
-        # needs to skip the full toggle-cache init after a scenario delta
-        self._warm_cache = {
-            "assignment": assign_np.copy(),
-            "cur": np.asarray(cur, np.float32),
-            "toggles": [np.asarray(t) for t in toggles],
-            "profile": profile,
-        }
-        return assign_np, member, moves, trace
+        kept, stale = (None, None) if warm is None else (warm[:2], warm[2])
+        with tracing.span("hfel.sweep.init"):
+            cache = tracing.ready(init(member0, self._buckets,
+                                       self.cloud_const, kept, stale))
+        with tracing.span("hfel.sweep.loop"):
+            out = tracing.ready(loop(
+                member0, assign0, key, *cache, self._buckets,
+                self._ex_bucket, self._slot_of, self._bucket_of,
+                self._row_of, self.cloud_const, self._cap,
+                jnp.float32(rel_tol)))
+        with tracing.span("hfel.readback"):
+            # one transfer for every output; the move trace is cut on the
+            # host, so no program is compiled per move count
+            member, assign, cur, toggles, moves, trace, counts = \
+                jax.device_get(out)
+            self.last_state = {"member": member, "cur_cost": cur}
+            if self.compact == "bucketed":
+                self.last_state.update(
+                    toggle_cost_buckets=list(toggles),
+                    reach_buckets=self.reach_buckets)
+            elif self.compact:
+                r = self.reach
+                self.last_state.update(
+                    member_compact=(member[np.arange(k)[:, None], r.idx]
+                                    & r.valid),
+                    toggle_cost_compact=toggles[0], reach=r)
+            else:
+                self.last_state.update(toggle_cost=toggles[0])
+            moves = int(moves)
+            self.last_moves = moves
+            self.last_counts = dict(zip(COUNT_NAMES, counts.tolist()))
+            trace = [float(x) for x in trace[:moves + 1].astype(np.float64)]
+            assign = assign.astype(np.int64)
+            # stable-point cache for rerun_incremental: everything a warm
+            # start needs to skip the full toggle-cache init after a
+            # scenario delta
+            self._warm_cache = {"assignment": assign.copy(), "cur": cur,
+                                "toggles": list(toggles), "profile": profile}
+        tracing.event("hfel.count", **self.last_counts)
+        return assign, member, moves, trace
 
     def _finalize(self, assignment, member, moves, trace) -> AssociationResult:
-        k = self.sc.n_servers
-        masks = np.asarray(member)
-        sols = self._eval_solver.solve_batch(np.arange(k), masks)
-        jmasks = jnp.asarray(masks)
-        f = np.asarray(jnp.sum(jnp.where(jmasks, sols.f, 0.0), axis=0))
-        beta = np.asarray(jnp.sum(jnp.where(jmasks, sols.beta, 0.0), axis=0))
-        server_cost = np.asarray(sols.cost)
-        total = float(np.sum(
-            server_cost + np.where(masks.any(axis=1),
-                                   np.asarray(self.cloud_const), 0.0)))
-        # true (15)-(17) costs are over the active population only: inactive
-        # devices hold no resources (f = beta = 0 in the masked sums above)
-        # and must not enter the per-device energy/delay terms
-        e, t, c = _true_cost_terms(self.sc, self._active, assignment, f, beta)
-        return AssociationResult(
-            assignment=assignment.copy(), f=f, beta=beta,
-            server_cost=server_cost, total_cost=total,
-            true_energy=float(e), true_delay=float(t), true_cost=float(c),
-            n_adjustments=moves, n_rounds=moves, cost_trace=trace)
+        with tracing.span("hfel.finalize"):
+            k = self.sc.n_servers
+            masks = np.asarray(member)
+            sols = self._eval_solver.solve_batch(np.arange(k), masks)
+            jmasks = jnp.asarray(masks)
+            f = np.asarray(jnp.sum(jnp.where(jmasks, sols.f, 0.0), axis=0))
+            beta = np.asarray(jnp.sum(jnp.where(jmasks, sols.beta, 0.0),
+                                      axis=0))
+            server_cost = np.asarray(sols.cost)
+            total = float(np.sum(
+                server_cost + np.where(masks.any(axis=1),
+                                       np.asarray(self.cloud_const), 0.0)))
+            # true (15)-(17) costs are over the active population only:
+            # inactive devices hold no resources (f = beta = 0 in the masked
+            # sums above) and must not enter the per-device energy/delay
+            # terms
+            e, t, c = _true_cost_terms(self.sc, self._active, assignment, f,
+                                       beta)
+            return AssociationResult(
+                assignment=assignment.copy(), f=f, beta=beta,
+                server_cost=server_cost, total_cost=total,
+                true_energy=float(e), true_delay=float(t), true_cost=float(c),
+                n_adjustments=moves, n_rounds=moves, cost_trace=trace)

@@ -62,6 +62,11 @@ re-solves; the runner hands ``rerun_incremental`` the single combined
 scenario and the current one, so one incremental re-solve absorbs any
 number of ticks.
 
+With :mod:`repro.utils.tracing` on, each round writes the host spans
+``hfel.live.churn`` (the churn tick), ``hfel.live.assoc`` (the policy's
+re-solve or repair) and ``hfel.live.accounting`` (the round's eq.-17
+cost) into the profiler's trace.
+
 Streaming admission under capacities
 ------------------------------------
 When the scenario carries per-edge caps (``Scenario.max_devices``), the
@@ -104,6 +109,7 @@ from repro.core.scenario import (DeviceClientBridge, Scenario,
                                  perturb_scenario)
 from repro.data.federated import FederatedDataset
 from repro.fl.training import TrainHistory, train_federated
+from repro.utils import tracing
 
 POLICIES = ("static", "periodic-cold", "incremental-warm")
 
@@ -349,9 +355,10 @@ class LiveHFELRunner:
         h = self.history
         # _eval_solver is None for "proportional" (distance-dependent):
         # assignment_true_cost then builds a fresh per-round solver itself
-        e, t, c = assignment_true_cost(self.sc, self.assignment,
-                                       solver=self._eval_solver,
-                                       kind=self.kind, seed=self.seed)
+        with tracing.span("hfel.live.accounting"):
+            e, t, c = assignment_true_cost(self.sc, self.assignment,
+                                           solver=self._eval_solver,
+                                           kind=self.kind, seed=self.seed)
         h.system_cost.append(c)
         h.system_energy.append(e)
         h.system_delay.append(t)
@@ -376,10 +383,11 @@ class LiveHFELRunner:
             trainer.client_mask = jnp.asarray(
                 self.bridge.client_mask(self.sc.active_mask))
             t0 = time.perf_counter()
-            self.engine = self._new_engine(self.sc)
-            assignment = self.engine.run(
-                "nearest", max_moves=self.max_moves,
-                exchange_samples=self.exchange_samples, finalize=False)
+            with tracing.span("hfel.live.assoc"):
+                self.engine = self._new_engine(self.sc)
+                assignment = self.engine.run(
+                    "nearest", max_moves=self.max_moves,
+                    exchange_samples=self.exchange_samples, finalize=False)
             assoc_s = time.perf_counter() - t0
             self.assignment = np.asarray(assignment)
             self._assign_at_swap = self.assignment.copy()
@@ -393,66 +401,70 @@ class LiveHFELRunner:
             return self.bridge.client_assignment(self.assignment)
 
         capped = self._admitted is not None
-        if capped:
-            admitted_before = self._admitted.copy()
-            self._sc_full, delta = churn_tick(self._sc_full, seed=self.seed,
-                                              r=r, churn=self.churn)
-            full_active = self._sc_full.active_mask
-            # true-scenario departures leave the admitted set and the queue;
-            # arrivals join the queue — streaming admission is the ONLY path
-            # into the training population under caps
-            self._admitted &= full_active
-            self._queue = [d for d in self._queue if full_active[d]]
-            self._queue.extend(np.flatnonzero(delta.arrived).tolist())
-            self._rebuild_view()
-        else:
-            self.sc, delta = churn_tick(self.sc, seed=self.seed, r=r,
-                                        churn=self.churn)
+        with tracing.span("hfel.live.churn"):
+            if capped:
+                admitted_before = self._admitted.copy()
+                self._sc_full, delta = churn_tick(
+                    self._sc_full, seed=self.seed, r=r, churn=self.churn)
+                full_active = self._sc_full.active_mask
+                # true-scenario departures leave the admitted set and the
+                # queue; arrivals join the queue — streaming admission is
+                # the ONLY path into the training population under caps
+                self._admitted &= full_active
+                self._queue = [d for d in self._queue if full_active[d]]
+                self._queue.extend(np.flatnonzero(delta.arrived).tolist())
+                self._rebuild_view()
+            else:
+                self.sc, delta = churn_tick(self.sc, seed=self.seed, r=r,
+                                            churn=self.churn)
         assoc_s, moves, swapped, admitted_n = 0.0, 0, False, 0
         resolve = self.policy != "static" and r % self.resolve_every == 0
-        if resolve and self.policy == "incremental-warm":
-            # the delta derivation is part of the warm path's per-swap work,
-            # so it belongs inside the association timer (cold's timer
-            # likewise spans its repair + engine build)
-            t0 = time.perf_counter()
-            if capped:
-                # pre-validate the engine's repair inputs: demote devices
-                # the capacitated repair cannot place, so the engine's own
-                # (deterministic, input-identical) repair cannot raise
-                self._repair_with_demotions(self.engine.stable_assignment,
-                                            self._active_at_swap)
-            combined = diff_scenarios(self._sc_at_swap, self.sc)
-            self.assignment = self.engine.rerun_incremental(
-                self.sc, combined, max_moves=self.max_moves,
-                exchange_samples=self.exchange_samples, verify=self.verify,
-                finalize=False)
-            assoc_s = time.perf_counter() - t0
-            moves, swapped = self.engine.last_moves, True
-        elif resolve:   # periodic-cold
-            t0 = time.perf_counter()
-            if capped:
-                assign0 = self._repair_with_demotions(self._assign_at_swap,
-                                                      self._active_at_swap)
+        with tracing.span("hfel.live.assoc"):
+            if resolve and self.policy == "incremental-warm":
+                # the delta derivation is part of the warm path's per-swap
+                # work, so it belongs inside the association timer (cold's
+                # timer likewise spans its repair + engine build)
+                t0 = time.perf_counter()
+                if capped:
+                    # pre-validate the engine's repair inputs: demote
+                    # devices the capacitated repair cannot place, so the
+                    # engine's own (deterministic, input-identical) repair
+                    # cannot raise
+                    self._repair_with_demotions(
+                        self.engine.stable_assignment, self._active_at_swap)
+                combined = diff_scenarios(self._sc_at_swap, self.sc)
+                self.assignment = self.engine.rerun_incremental(
+                    self.sc, combined, max_moves=self.max_moves,
+                    exchange_samples=self.exchange_samples,
+                    verify=self.verify, finalize=False)
+                assoc_s = time.perf_counter() - t0
+                moves, swapped = self.engine.last_moves, True
+            elif resolve:   # periodic-cold
+                t0 = time.perf_counter()
+                if capped:
+                    assign0 = self._repair_with_demotions(
+                        self._assign_at_swap, self._active_at_swap)
+                else:
+                    assign0, *_ = repair_assignment(
+                        self.sc, self._assign_at_swap, self._active_at_swap)
+                cold = self._new_engine(self.sc)
+                assignment = cold.run(assignment=assign0,
+                                      max_moves=self.max_moves,
+                                      exchange_samples=self.exchange_samples,
+                                      finalize=False)
+                assoc_s = time.perf_counter() - t0
+                self.assignment = np.asarray(assignment)
+                moves, swapped = cold.last_moves, True
             else:
-                assign0, *_ = repair_assignment(self.sc, self._assign_at_swap,
-                                                self._active_at_swap)
-            cold = self._new_engine(self.sc)
-            assignment = cold.run(assignment=assign0,
-                                  max_moves=self.max_moves,
-                                  exchange_samples=self.exchange_samples,
-                                  finalize=False)
-            assoc_s = time.perf_counter() - t0
-            self.assignment = np.asarray(assignment)
-            moves, swapped = cold.last_moves, True
-        else:
-            # static policy, and the off-cycle rounds of the re-association
-            # policies: minimal feasibility repair, zero descent moves
-            if capped:
-                self.assignment = self._repair_with_demotions(
-                    self.assignment, self._active_prev)
-            else:
-                self.assignment, *_ = repair_assignment(
-                    self.sc, self.assignment, self._active_prev)
+                # static policy, and the off-cycle rounds of the
+                # re-association policies: minimal feasibility repair, zero
+                # descent moves
+                if capped:
+                    self.assignment = self._repair_with_demotions(
+                        self.assignment, self._active_prev)
+                else:
+                    self.assignment, *_ = repair_assignment(
+                        self.sc, self.assignment, self._active_prev)
         if swapped:
             # swap refs are stored PRE-drain: the next warm re-solve diffs
             # against (and the next cold rebuild repairs from) exactly the
