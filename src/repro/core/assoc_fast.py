@@ -31,7 +31,12 @@ zero solver calls, picks the best permitted move via ``lax`` reductions with
 an explicit device-major tie-break key, and only then refreshes the cache. A
 move touches exactly two servers, so the refresh solves each touched server's
 current group plus its single-slot toggles — ``R_b + 1`` groups of vector
-width ``R_b``, dispatched to the server's bucket with ``lax.switch``.
+width ``R_b``. With one bucket and no sharding (the dense and flat compact
+spaces) both rows go through ONE batched solve of ``2 (R + 1)`` groups: the
+solver's sequential depth does not grow with its batch, so this costs far
+less than two solves in turn. Bucketed and sharded sweeps dispatch each row
+to its server's bucket with its own ``lax.switch``, since the two rows may
+differ in width or live on different shards.
 
 There is exactly one move-selection loop body (:func:`_run_device`); the
 historical dense / compacted engines are *configurations* of it:
@@ -103,8 +108,9 @@ come from ``XLA_FLAGS=--xla_force_host_platform_device_count=<p>``.
 ``ra_backend="pallas"`` additionally routes every batched group solve of
 the ``fast`` kind through the fused golden-section kernel
 (:mod:`repro.kernels.golden_section`) instead of the vmapped op-by-op XLA
-graph — one kernel call per R_b+1-group refresh. It matches the XLA solver
-to float32 rounding (not bit-exactly), so the default stays ``"xla"``.
+graph — one kernel call per refresh (2 (R + 1) groups when both rows are
+fused, R_b + 1 otherwise). It matches the XLA solver to float32 rounding
+(not bit-exactly), so the default stays ``"xla"``.
 
 Two-tier descent (:meth:`FastAssociationEngine.run_tiered`)
 -----------------------------------------------------------
@@ -196,10 +202,13 @@ DEFAULT_EXCHANGE_SAMPLES = 64
 #: cold); ``iterations``: move-loop iterations; ``transfers``/``exchanges``:
 #: moves applied of each kind; ``exchange_tries``: iterations that took the
 #: exchange branch; ``loop_groups``: group solves issued by the refreshes
-#: and the exchange pricing. Under sharding the solve counts cover the
-#: padded rows and samples every shard prices.
+#: and the exchange pricing; ``fused_refreshes``: iterations whose two
+#: touched rows were refreshed in one batched solve (every applied move of
+#: a single-bucket unsharded sweep, 0 elsewhere). Under sharding the solve
+#: counts cover the padded rows and samples every shard prices.
 COUNT_NAMES = ("init_groups", "stale_rows", "iterations", "transfers",
-               "exchange_tries", "exchanges", "loop_groups")
+               "exchange_tries", "exchanges", "loop_groups",
+               "fused_refreshes")
 
 
 class _Bucket(NamedTuple):
@@ -460,6 +469,11 @@ def _run_device_impl(member, assignment, key, cur0, toggles0, counts0,
     # group solves of one refresh per bucket, and 0 for the no-op branch
     refresh_groups = jnp.asarray([bd.idx.shape[1] + 1 for bd in buckets]
                                  + [0], i32)
+    # one bucket, unsharded (the dense and flat compact spaces): both
+    # touched rows are refreshed in one batched solve (refresh_pair).
+    # Bucketed rows may differ in width and sharded rows in owner, so those
+    # keep one lax.switch per row (refresh_server).
+    fused = nb == 1 and axis is None
 
     trace0 = jnp.full(max_moves + 1, jnp.nan, cur0.dtype)
     trace0 = trace0.at[0].set(jnp.sum(cur0))
@@ -506,6 +520,26 @@ def _run_device_impl(member, assignment, key, cur0, toggles0, counts0,
                 which, [branch(b) for b in range(nb)] + [lambda ops: ops],
                 (cur, toggles))
         return cur, toggles, refresh_groups[which]
+
+    def refresh_pair(member, servers, applied, cur, toggles):
+        """Refresh both touched servers' rows in ONE batched solve of
+        2 (R + 1) groups: with one bucket and no sharding both rows share
+        the width and live on this device, and the sequential depth of a
+        batched solve does not grow with its batch. The two servers differ
+        (a transfer has src != dst, an exchange si != sj), so the writes
+        are those of two single-row refreshes."""
+
+        def go(ops):
+            cur, (tog,) = ops
+            rows = row_of[servers]
+            costs = rows_costs(0, member, rows)                # (2, R+1)
+            return (cur.at[servers].set(costs[:, 0]),
+                    (tog.at[rows].set(costs[:, 1:]),))
+
+        with jax.named_scope("hfel.refresh"):
+            cur, toggles = lax.cond(applied, go, lambda ops: ops,
+                                    (cur, toggles))
+        return cur, toggles, jnp.where(applied, 2 * refresh_groups[0], 0)
 
     def body(state):
         member, assign, cur, toggles, moves, key, trace, _, work = state
@@ -675,10 +709,15 @@ def _run_device_impl(member, assignment, key, cur0, toggles0, counts0,
             applied, rows, member, assign, key = lax.cond(
                 has_transfer, do_transfer, no_exchange, args)
             tried = jnp.asarray(False)
-        cur, toggles, g0 = refresh_server(member, rows[0], applied, cur,
-                                          toggles)
-        cur, toggles, g1 = refresh_server(member, rows[1], applied, cur,
-                                          toggles)
+        if fused:
+            cur, toggles, groups = refresh_pair(member, rows, applied, cur,
+                                                toggles)
+        else:
+            cur, toggles, g0 = refresh_server(member, rows[0], applied, cur,
+                                              toggles)
+            cur, toggles, g1 = refresh_server(member, rows[1], applied, cur,
+                                              toggles)
+            groups = g0 + g1
         if axis is not None:
             # only the touched servers' owners re-solved their cur entries;
             # re-replicate exactly those two (psum of owner-only values)
@@ -692,7 +731,8 @@ def _run_device_impl(member, assignment, key, cur0, toggles0, counts0,
         work = work + _counts(
             iterations=1, transfers=applied & has_transfer,
             exchange_tries=tried, exchanges=applied & ~has_transfer,
-            loop_groups=g0 + g1 + tried.astype(i32) * (2 * ex_chunk))
+            loop_groups=groups + tried.astype(i32) * (2 * ex_chunk),
+            fused_refreshes=applied & fused)
         return (member, assign, cur, toggles, moves, key, trace, ~applied,
                 work)
 

@@ -179,38 +179,68 @@ def test_pallas_backend_engine_matches_xla():
 # bit-identical to the single-device engine across every sweep space ×
 # shard count × exchange setting. The (16, 4, seed=1) geometry is the one
 # the exchange tests pin (transfers alone stall short of the exchange-on
-# stable point, so the escape path genuinely fires).
-EXCHANGE_MATRIX = [(c, p, s)
-                   for c in ("bucketed", True, False)
-                   for p in (1, 3, 4)
-                   for s in (0, 64)]
+# stable point, so the escape path genuinely fires). In the dense and flat
+# spaces the single-device engine refreshes both touched rows in one
+# batched solve and the sharded one row by row, so these cases also hold
+# the fused refresh to the unfused one, for the exact solver too.
+EXCHANGE_MATRIX = ([(c, p, s, "fast")
+                    for c in ("bucketed", True, False)
+                    for p in (1, 3, 4)
+                    for s in (0, 64)]
+                   + [(c, 1, s, "optimal")
+                      for c in (True, False)
+                      for s in (0, 64)])
+# counters whose value does not depend on the row padding of a mesh
+SHARD_FREE_COUNTS = ("stale_rows", "iterations", "transfers",
+                     "exchange_tries", "exchanges")
+
+
+def _cache(eng):
+    """The sweep's final cur/toggle cache, each bucket cut to its real rows
+    (a sharded layout pads them at the end)."""
+    warm = eng._warm_cache
+    return warm["cur"], [t[:np.asarray(bd.servers < eng.sc.n_servers).sum()]
+                         for t, bd in zip(warm["toggles"], eng._buckets)]
 
 
 @pytest.mark.parametrize(
-    "compact,shards,samples", EXCHANGE_MATRIX,
+    "compact,shards,samples,kind", EXCHANGE_MATRIX,
     ids=[f"{'dense' if c is False else 'flat' if c is True else c}"
-         f"-p{p}-ex{s}" for c, p, s in EXCHANGE_MATRIX])
-def test_sharded_exchange_parity_matrix(compact, shards, samples):
+         f"-p{p}-ex{s}" + ("" if kd == "fast" else f"-{kd}")
+         for c, p, s, kd in EXCHANGE_MATRIX])
+def test_sharded_exchange_parity_matrix(compact, shards, samples, kind):
     """Distributed sampled exchanges (PR 10): the replicated pair proposal +
     chunk-partitioned pricing + all_gather (delta, sample-order) winner fold
     must reproduce the single-device exchange sequence bit-for-bit — same
-    assignment, same move count, same per-move cost trace."""
+    assignment, same move count, same per-move cost trace, same final
+    toggle-cost cache and the same work counters."""
     if shards > N_DEV:
         pytest.skip("needs XLA_FLAGS=--xla_force_host_platform_device_count")
     sc = make_scenario(16, 4, seed=1, reach_m=300.0)
-    classic = FastAssociationEngine(sc, kind="fast", seed=0,
-                                    compact=compact).run(
-        "nearest", exchange_samples=samples)
-    sharded = FastAssociationEngine(sc, kind="fast", seed=0, compact=compact,
-                                    shards=shards).run(
-        "nearest", exchange_samples=samples)
+    classic_eng = FastAssociationEngine(sc, kind=kind, seed=0,
+                                        compact=compact)
+    classic = classic_eng.run("nearest", exchange_samples=samples)
+    sharded_eng = FastAssociationEngine(sc, kind=kind, seed=0,
+                                        compact=compact, shards=shards)
+    sharded = sharded_eng.run("nearest", exchange_samples=samples)
     assert np.array_equal(classic.assignment, sharded.assignment)
     assert classic.n_adjustments == sharded.n_adjustments
     assert classic.cost_trace == sharded.cost_trace  # per-move, bitwise
+    (cur_c, tog_c), (cur_s, tog_s) = _cache(classic_eng), _cache(sharded_eng)
+    assert np.array_equal(cur_c, cur_s)
+    assert all(np.array_equal(a, b) for a, b in zip(tog_c, tog_s))
+    a, b = classic_eng.last_counts, sharded_eng.last_counts
+    same = (SHARD_FREE_COUNTS if shards > 1
+            else [c for c in a if c != "fused_refreshes"])
+    assert {c: a[c] for c in same} == {c: b[c] for c in same}
+    single_bucket = compact != "bucketed"
+    assert a["fused_refreshes"] == (classic.n_adjustments if single_bucket
+                                    else 0)
+    assert b["fused_refreshes"] == 0
     if samples:
         # the geometry guarantees the exchange branch fires: with exchanges
         # the descent moves strictly beyond the transfers-only stable point
-        no_ex = FastAssociationEngine(sc, kind="fast", seed=0,
+        no_ex = FastAssociationEngine(sc, kind=kind, seed=0,
                                       compact=compact).run(
             "nearest", exchange_samples=0)
         assert classic.n_adjustments > no_ex.n_adjustments

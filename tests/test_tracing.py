@@ -209,9 +209,12 @@ def test_counters_match_the_closed_form(compact, exchange_samples,
     refresh_groups = c["loop_groups"] - exchange_groups
     if len(widths) == 1:
         assert refresh_groups == 2 * widths[0] * moves
+        # one bucket, unsharded: every applied move's two rows in one solve
+        assert c["fused_refreshes"] == moves
     else:
         assert 2 * min(widths) * moves <= refresh_groups
         assert refresh_groups <= 2 * max(widths) * moves
+        assert c["fused_refreshes"] == 0
 
     # warm: only the stale rows are re-priced at init
     seen = {}
@@ -250,6 +253,7 @@ def test_sharded_counters_count_the_padding(shards):
     for name in ("stale_rows", "iterations", "transfers", "exchange_tries",
                  "exchanges"):
         assert b[name] == a[name], name
+    assert a["fused_refreshes"] == b["fused_refreshes"] == 0
     per_try = 2 * -(-7 // shards) * shards
     assert (b["loop_groups"] - per_try * b["exchange_tries"]
             == a["loop_groups"] - 14 * a["exchange_tries"])
