@@ -191,14 +191,12 @@ def kernel_phase(sc, seed: int) -> None:
             sols[backend] = jax.block_until_ready(compiled(c, masks))
             log(f"[kernel] {profile} {backend} G={g} R={r} "
                 f"{(time.perf_counter() - t0) * 1e3:.3f} ms")
-        # Per group the two backends need not agree: the cost along the
-        # deadline path is not unimodal for some groups, so an ulp-level
-        # difference can send golden section to another local minimum (on
-        # the chip the XLA solver moves as far under a 2-ulp change of its
-        # own inputs), and where the cost is flat in the deadline the
-        # deadline is not determined at all. So the pin must hold for the
-        # cost, which the sweep consumes, in KERNEL_CORE_SHARE of the
-        # groups, and for the deadline in the median group.
+        # Per group the two backends need not agree bit for bit: they
+        # fuse the same float32 arithmetic differently, and where the cost
+        # is flat in the deadline the deadline is not determined at all.
+        # So the pin must hold for the cost, which the sweep consumes, in
+        # KERNEL_CORE_SHARE of the groups, and for the deadline in the
+        # median group.
         for field, share in (("cost", KERNEL_CORE_SHARE), ("deadline", 0.5)):
             got = np.asarray(getattr(sols["pallas"], field), np.float64)
             want = np.asarray(getattr(sols["xla"], field), np.float64)

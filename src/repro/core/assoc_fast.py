@@ -142,10 +142,12 @@ Tracing (:mod:`repro.utils.tracing`, off by default): host spans
 ``hfel.build.{solver,reach,space}``, ``hfel.init_assign``,
 ``hfel.sweep.init``, ``hfel.sweep.loop`` (each device span closes once the
 device is done), ``hfel.readback``, ``hfel.finalize``,
-``hfel.rerun.{patch,repair}``, and one ``hfel.count`` event per sweep
-carrying the :data:`COUNT_NAMES` counters. The device code carries the
-``named_scope`` names ``hfel.init``, ``hfel.scan``, ``hfel.exchange``,
-``hfel.refresh`` and ``hfel.solve.{xla,pallas}``.
+``hfel.rerun.{patch,repair}``, one ``hfel.count`` event per sweep
+carrying the :data:`COUNT_NAMES` counters, and one ``hfel.rerun.count``
+event per warm re-solve carrying the :data:`RERUN_COUNT_NAMES` counts.
+The device code carries the ``named_scope`` names ``hfel.init``,
+``hfel.scan``, ``hfel.exchange``, ``hfel.refresh`` and
+``hfel.solve.{xla,pallas}``.
 """
 
 from __future__ import annotations
@@ -209,6 +211,14 @@ DEFAULT_EXCHANGE_SAMPLES = 64
 COUNT_NAMES = ("init_groups", "stale_rows", "iterations", "transfers",
                "exchange_tries", "exchanges", "loop_groups",
                "fused_refreshes")
+
+#: Host counts of one warm re-solve (:meth:`FastAssociationEngine.
+#: rerun_incremental`), added to ``last_counts`` after the sweep's: active
+#: devices that ``departed``, parked ones that ``arrived``, active devices
+#: ``displaced`` from a server they no longer reach, and ``reach_rebuilds``,
+#: reach maps rebuilt at a new width (the flat map, or each rebuilt bucket;
+#: a new width compiles the sweep again).
+RERUN_COUNT_NAMES = ("departed", "arrived", "displaced", "reach_rebuilds")
 
 
 class _Bucket(NamedTuple):
@@ -979,8 +989,8 @@ class FastAssociationEngine:
                              f"got {ra_backend!r}")
         if ra_backend == "pallas" and kind != "fast":
             raise ValueError(
-                "ra_backend='pallas' fuses the golden-section fixed-point "
-                "solver and therefore requires kind='fast'")
+                "ra_backend='pallas' fuses the fast kind's group solve "
+                "and therefore requires kind='fast'")
         self.ra_backend = ra_backend
         # ``shards=None`` is the classic single-device program (bit-exact
         # contract); ``shards=p`` runs the SAME impl shard_mapped over the
@@ -1036,7 +1046,7 @@ class FastAssociationEngine:
                 if compact in (True, "bucketed"):
                     raise
             if compact == "auto":
-                if self.reach is None or self.reach.r_max >= sc.n_devices:
+                if self.reach is None or self.reach.widest >= sc.n_devices:
                     compact = False
                 else:
                     # sparse reach -> compact; heavily padded flat maps
@@ -1056,7 +1066,8 @@ class FastAssociationEngine:
         self.last_state: dict | None = None   # debug: cur/toggle cache dump
         self.last_tier_moves: list[int] | None = None
         self.last_moves: int | None = None    # applied moves of the last sweep
-        # the last sweep's work counters, by COUNT_NAMES
+        # the last sweep's work counters, by COUNT_NAMES (and, after a warm
+        # re-solve, its host counts, by RERUN_COUNT_NAMES)
         self.last_counts: dict[str, int] | None = None
         self._warm_cache: dict | None = None  # rerun_incremental state
         self.last_repaired_assignment: np.ndarray | None = None
@@ -1338,12 +1349,15 @@ class FastAssociationEngine:
                     changed_servers=delta.stale_servers)
             else:
                 self.reach = None
+            rebuilds = 0
             if self.compact == "bucketed":
                 self.reach_buckets, carry = update_reach_buckets(
                     self.reach_buckets, raw, active=self._active,
                     changed_servers=delta.stale_servers)
+                rebuilds = sum(c is None for c in carry)
             elif self.compact:
                 carry = [None] if flat_rebuilt else [0]
+                rebuilds = int(flat_rebuilt)
             elif self.kind == "proportional" and delta.moved.any():
                 # dense toggle rows span every device, so a moved device's
                 # inv_dist change can touch any row's cached cost
@@ -1379,6 +1393,11 @@ class FastAssociationEngine:
         assignment, member, moves, trace = self._sweep(
             assign, profile, max_moves, exchange_samples,
             jax.random.PRNGKey(self.seed), warm=warm)
+        host = dict(zip(RERUN_COUNT_NAMES, (
+            int(departed.sum()), int(arrived.sum()), int(displaced.sum()),
+            rebuilds)))
+        self.last_counts.update(host)
+        tracing.event("hfel.rerun.count", **host)
         if verify:
             cold = FastAssociationEngine(
                 sc_new, kind=self.kind, permission=self.permission,

@@ -116,7 +116,7 @@ class GroupSolver:
 
     ``kind`` selects the resource-allocation scheme of §V.A:
       optimal      — solve_exact            (full joint optimization)
-      fast         — solve_fixed_point      (screening-grade joint opt.)
+      fast         — solve_fixed_point      (KKT solve, ~1k steps deep)
       paper        — solve_paper            (Algorithm 2 faithful)
       comp_only    — optimal f, uniform beta
       comm_only    — optimal beta, random fixed f

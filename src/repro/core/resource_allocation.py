@@ -17,12 +17,12 @@ Four solvers are provided; all are jit-able and vmap-able over padded groups
   f-only convex problem (32) by a projected first-order method with an
   annealed log-sum-exp smoothing of the max (standing in for the paper's
   "CVX / IPOPT").
-* :func:`solve_fixed_point`  — fast beyond-paper solver exploiting the full
-  KKT structure: at the optimum every device with interior f finishes at a
-  common deadline t (eq. 25 with tau_n = 2 b_n f_n^3 / e_n > 0) and
-  sum_n tau_n = W (eq. 23); bisection on t with an inner beta<->f fixed
-  point. Near-exact in the common interior regime; used to screen the many
-  candidate groups of edge association.
+* :func:`solve_fixed_point`  — the fast kind: the optimum of (18) from its
+  KKT conditions in every regime (:func:`kkt_rows`): golden section over
+  the common deadline t, Newton on the bandwidth price nu, and per device
+  either the untight f_min case or a Newton root of the tight pair. About
+  a thousand dependent steps; prices the many candidate groups of edge
+  association.
 * :func:`solve_exact`        — exact nested parametric solver: golden-section
   over the deadline t, bisection over the bandwidth multiplier nu, per-device
   golden-section for the (convex) boundary trade-off. Handles all box/cap
@@ -42,9 +42,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core.cost_model import RAConstants, ra_objective
-
-_GOLDEN = 0.6180339887498949
-_EPS = 1e-12
+from repro.kernels.kkt import EPS as _EPS
+from repro.kernels.kkt import golden_min as _golden_min
+from repro.kernels.kkt import kkt_rows
 
 
 def _register(cls):
@@ -60,40 +60,6 @@ class RASolution:
     beta: jnp.ndarray       # (N,) optimal bandwidth shares (padded: 0)
     cost: jnp.ndarray       # scalar, optimal value of (18); 0 for empty group
     deadline: jnp.ndarray   # scalar t* = max_n d/beta + e/f
-
-
-def _golden_min(fn, lo, hi, n_iter: int):
-    """Golden-section minimize with the classic single-eval recurrence.
-
-    Each iteration shrinks the bracket by the golden ratio while evaluating
-    ``fn`` ONCE (the surviving interior probe is reused via G^2 = 1 - G),
-    instead of the two evaluations per iteration of the naive form — the
-    dominant sequential-depth cost of every solver here. ``lo``/``hi`` may be
-    arrays (vectorized independent searches); returns the bracket midpoint.
-    """
-    m1 = hi - _GOLDEN * (hi - lo)
-    m2 = lo + _GOLDEN * (hi - lo)
-    c1, c2 = fn(m1), fn(m2)
-
-    def body(_, st):
-        lo, hi, m1, m2, c1, c2 = st
-        go_right = c1 > c2
-        lo = jnp.where(go_right, m1, lo)
-        hi = jnp.where(go_right, hi, m2)
-        m1n = hi - _GOLDEN * (hi - lo)
-        m2n = lo + _GOLDEN * (hi - lo)
-        # the surviving probe becomes the opposite interior point; only the
-        # freshly exposed point needs an evaluation
-        point = jnp.where(go_right, m2n, m1n)
-        cp = fn(point)
-        m1_new = jnp.where(go_right, m2, point)
-        c1_new = jnp.where(go_right, c2, cp)
-        m2_new = jnp.where(go_right, point, m1)
-        c2_new = jnp.where(go_right, cp, c1)
-        return lo, hi, m1_new, m2_new, c1_new, c2_new
-
-    lo, hi, *_ = lax.fori_loop(0, n_iter, body, (lo, hi, m1, m2, c1, c2))
-    return 0.5 * (lo + hi)
 
 
 def _masked_beta_norm(s: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
@@ -171,7 +137,7 @@ def solve_paper(c: RAConstants, mask: jnp.ndarray, *, n_steps: int = 400) -> RAS
 
 
 # ---------------------------------------------------------------------------
-# Solver 2 — KKT fixed point (fast screening solver)
+# Solver 2 — KKT solve (the fast kind)
 # ---------------------------------------------------------------------------
 
 def _deadline_bracket(c: RAConstants, mask, n_bracket: int = 60):
@@ -203,16 +169,18 @@ def _deadline_bracket(c: RAConstants, mask, n_bracket: int = 60):
     return hi_[0], hi_[1]
 
 
-# Iteration presets for :func:`solve_fixed_point`. "default" is the reference
-# accuracy used by the association parity gates; "screen" / "coarse" trade a
-# little deadline resolution for 2-4x fewer inner iterations when the solver
-# runs inside the fused candidate sweeps of ``repro.core.assoc_fast`` at large
-# device counts (every candidate group pays n_golden * n_inner + 2 * n_bracket
-# vector ops, so these knobs dominate sweep cost).
+# Iteration presets for :func:`solve_fixed_point` (see :func:`kkt_rows`):
+# golden-section steps over the deadline, Newton steps of the bandwidth
+# price, Newton steps of each device's communication time at each price,
+# and bisection steps of the least feasible deadline. "default" prices
+# groups within ~1e-6 of the optimum of (18) on a CPU; "screen" / "coarse"
+# trade that for a shallower solve (dependent depth 1158, 714 and 491
+# steps) when the solver runs inside the fused candidate sweeps of
+# ``repro.core.assoc_fast``.
 SCREEN_PROFILES: dict[str, dict[str, int]] = {
-    "default": dict(n_golden=48, n_inner=12, n_bracket=60),
-    "screen": dict(n_golden=32, n_inner=8, n_bracket=40),
-    "coarse": dict(n_golden=16, n_inner=6, n_bracket=24),
+    "default": dict(n_golden=24, n_price=5, n_root=5, n_bracket=24),
+    "screen": dict(n_golden=20, n_price=4, n_root=4, n_bracket=24),
+    "coarse": dict(n_golden=16, n_price=4, n_root=3, n_bracket=16),
 }
 
 # Named multi-tier descent plans for the association engines: each entry is a
@@ -248,50 +216,29 @@ def resolve_tiers(tiers) -> tuple[str, ...]:
     return tiers
 
 
-@partial(jax.jit, static_argnames=("n_golden", "n_inner", "n_bracket"))
-def solve_fixed_point(c: RAConstants, mask: jnp.ndarray, *, n_golden: int = 48,
-                      n_inner: int = 12, n_bracket: int = 60) -> RASolution:
-    """Golden-section on the common deadline t along the KKT path.
+@partial(jax.jit, static_argnames=("n_golden", "n_price", "n_root",
+                                   "n_bracket"))
+def solve_fixed_point(c: RAConstants, mask: jnp.ndarray, *, n_golden: int = 24,
+                      n_price: int = 5, n_root: int = 5,
+                      n_bracket: int = 24) -> RASolution:
+    """The fast kind's group solve: the optimum of (18) from its KKT
+    conditions (:func:`kkt_rows`), at a dependent depth of about a
+    thousand steps where :func:`solve_exact` takes ~81k.
 
-    At a fixed t, beta follows eq. (19) and f the tightness relation
-    f_n = clip(e_n / (t - d_n/beta_n), box) — iterated as a fixed point.
-    Rather than root-finding the eq.-(23) residual sum tau_n = W (which has
-    no root once box constraints clip f, and then misplaces t badly), the
-    *exact objective* (18) is evaluated along this one-parameter family and
-    minimized by golden-section: exact whenever the interior KKT structure
-    holds, and never pathological when it does not.
-
-    ``(n_golden, n_inner, n_bracket)`` presets live in :data:`SCREEN_PROFILES`.
+    ``(n_golden, n_price, n_root, n_bracket)`` presets live in
+    :data:`SCREEN_PROFILES`.
     """
-    t_lo, t_hi = _deadline_bracket(c, mask, n_bracket)
-    t_lo = t_lo * (1.0 + 1e-6)
-    t_hi = jnp.maximum(t_hi * 1.5, t_lo * 4.0) + 1.0
-
-    def fb_of_t(t):
-        def body(_, f):
-            beta = beta_of_f(c, mask, f)
-            safe_beta = jnp.where(mask, jnp.maximum(beta, _EPS), 1.0)
-            slack = t - c.d / safe_beta
-            f_new = jnp.where(slack > 0, c.e / jnp.maximum(slack, _EPS), c.f_max)
-            return jnp.clip(f_new, c.f_min, c.f_max)
-
-        f = lax.fori_loop(0, n_inner, body, jnp.sqrt(c.f_min * c.f_max))
-        return f, beta_of_f(c, mask, f)
-
-    def cost_of_t(t):
-        f, beta = fb_of_t(t)
-        safe_beta = jnp.where(mask, jnp.maximum(beta, _EPS), 1.0)
-        return ra_objective(c, mask, f, safe_beta)
-
-    f, beta = fb_of_t(_golden_min(cost_of_t, t_lo, t_hi, n_golden))
-    return _finalize(c, mask, f, beta)
+    f, beta, cost, deadline = kkt_rows(
+        c.a, c.b, c.d, c.e, c.w, c.f_min, c.f_max, mask, n_golden=n_golden,
+        n_price=n_price, n_root=n_root, n_bracket=n_bracket)
+    return RASolution(f=f, beta=beta, cost=cost[0], deadline=deadline[0])
 
 
 def solve_fixed_point_batched(c: RAConstants, masks: jnp.ndarray, *,
-                              n_golden: int = 48, n_inner: int = 12,
-                              n_bracket: int = 60,
+                              n_golden: int = 24, n_price: int = 5,
+                              n_root: int = 5, n_bracket: int = 24,
                               backend: str = "xla") -> RASolution:
-    """Solve a BATCH of independent groups along the KKT deadline path.
+    """Solve a BATCH of independent groups with the fast kind's KKT solve.
 
     ``c`` holds the constants batched over groups — leaves ``(G, R)``, ``w``
     ``(G,)`` — and ``masks`` is ``(G, R)``. ``backend`` selects the engine:
@@ -300,23 +247,20 @@ def solve_fixed_point_batched(c: RAConstants, masks: jnp.ndarray, *,
       traced per-group graph is identical to the scalar solver's, so results
       are bit-identical to solving each group alone.
     * ``"pallas"`` — the fused :mod:`repro.kernels.golden_section` kernel
-      (interpret mode off-TPU): the whole bracket + golden-section + inner
-      fixed-point stack runs as one VMEM-resident kernel per group block.
-      Matches the XLA path to float32 rounding, not bit-exactly — parity is
-      pinned at rtol 2e-4 on cost (tests/test_assoc_sharded.py); a group
-      whose cost along the deadline path is not unimodal can land in
-      another local minimum (see :mod:`repro.kernels.golden_section`).
+      (interpret mode off-TPU): the same :func:`kkt_rows` runs as one
+      VMEM-resident kernel per group block. It matches the XLA path to
+      float32 rounding, not bit-exactly; parity is pinned at rtol 2e-4 on
+      cost (tests/test_assoc_sharded.py).
     """
+    iters = dict(n_golden=n_golden, n_price=n_price, n_root=n_root,
+                 n_bracket=n_bracket)
     if backend == "xla":
-        return jax.vmap(
-            lambda cc, m: solve_fixed_point(cc, m, n_golden=n_golden,
-                                            n_inner=n_inner,
-                                            n_bracket=n_bracket))(c, masks)
+        return jax.vmap(lambda cc, m: solve_fixed_point(cc, m, **iters))(
+            c, masks)
     if backend == "pallas":
         from repro.kernels import ops as _kops
         f, beta, cost, deadline = _kops.golden_section_solve(
-            c.a, c.b, c.d, c.e, c.w, c.f_min, c.f_max, masks,
-            n_golden=n_golden, n_inner=n_inner, n_bracket=n_bracket)
+            c.a, c.b, c.d, c.e, c.w, c.f_min, c.f_max, masks, **iters)
         return RASolution(f=f, beta=beta, cost=cost, deadline=deadline)
     raise ValueError(f"unknown RA backend {backend!r}; "
                      "expected 'xla' or 'pallas'")

@@ -34,8 +34,10 @@ class ReachIndex:
     reach count); ``valid[k, r]`` marks real slots; ``slot[k, n]`` inverts the
     map (slot of device ``n`` at server ``k``, or ``r_max`` when ``n`` is out
     of reach — a deliberate out-of-range sentinel so one-hot encodings of an
-    invalid slot are all-zero). ``r_max`` is the widest reach count, i.e. the
-    compacted buffer width shared by all servers.
+    invalid slot are all-zero). ``r_max`` is the compacted buffer width
+    shared by all servers: the widest reach count plus headroom
+    (:func:`flat_width`), so that reach counts can grow under churn without
+    a new width.
     """
 
     idx: np.ndarray        # (K, R) int32
@@ -44,13 +46,23 @@ class ReachIndex:
     r_max: int
 
     @property
+    def widest(self) -> int:
+        """The widest reach count (``r_max`` less the headroom)."""
+        return int(self.valid.sum(axis=1).max()) if self.valid.size else 0
+
+    @property
     def density(self) -> float:
         return float(self.valid.mean())
 
     @property
     def padded_fraction(self) -> float:
-        """Fraction of compacted slots that are padding (wasted sweep work)."""
-        return 1.0 - self.density
+        """Fraction of slots up to the widest reach count that are padding:
+        the waste that skewed reach counts cause (the headroom past the
+        widest count is not counted)."""
+        widest = self.widest
+        if not widest:
+            return 0.0
+        return 1.0 - float(self.valid.sum()) / (self.valid.shape[0] * widest)
 
 
 @dataclass(frozen=True)
@@ -105,6 +117,22 @@ def _fill_reach_row(reach: np.ndarray, idx_row: np.ndarray,
     slot_row[reach] = np.arange(reach.size, dtype=np.int32)
 
 
+#: Lane width of the chip's vector registers: flat reach maps are allocated
+#: in whole multiples of it (:func:`flat_width`).
+LANES = 128
+
+
+def flat_width(count: int, n_devices: int) -> int:
+    """Allocated width of a flat reach map whose widest reach count is
+    ``count``: at least an eighth more than ``count`` and one slot, rounded
+    up to whole :data:`LANES` (a narrower row fills the same vector tiles
+    on the chip), and never more than ``n_devices``. A churn tick then keeps
+    the width, and every compiled shape, until some server's reach count
+    passes it."""
+    want = count + count // 8 + 1
+    return min(-(-want // LANES) * LANES, n_devices)
+
+
 def reach_index_map(avail: np.ndarray, *, bucketed: bool = False,
                     active: np.ndarray | None = None):
     """Compute the compacted reachable-set index maps of ``avail`` (K, N).
@@ -116,6 +144,7 @@ def reach_index_map(avail: np.ndarray, *, bucketed: bool = False,
     one device only if it is ever used; zero-reach *devices* are rejected
     because they cannot be associated anywhere (constraint 17e).
 
+    The flat map is allocated at :func:`flat_width` of the widest count.
     ``bucketed=True`` returns :class:`ReachBuckets` instead: servers are
     grouped by ``ceil(log2(reach_count))`` and each bucket is compacted at
     its own width, so one dense-reach server no longer pads every other
@@ -146,10 +175,12 @@ def reach_index_map(avail: np.ndarray, *, bucketed: bool = False,
                             valid[row], slot[srv], r_max)
         return idx, valid
 
-    slot = np.full((k, n), r_max, dtype=np.int32)
     if not bucketed:
+        r_max = flat_width(r_max, n)
+        slot = np.full((k, n), r_max, dtype=np.int32)
         idx, valid = fill(range(k), r_max, slot)
         return ReachIndex(idx=idx, valid=valid, slot=slot, r_max=r_max)
+    slot = np.full((k, n), r_max, dtype=np.int32)
 
     # binary bucketing: key = ceil(log2(count)); a zero-reach server (legal
     # when it is simply never used) joins the narrowest bucket
@@ -508,7 +539,8 @@ def update_reach_index(ri: ReachIndex, avail: np.ndarray, *,
     delta: changed servers' idx/valid/slot rows are rewritten at the map's
     existing allocated width (kept even when the new max reach count is
     smaller, so compiled shapes downstream survive); if any server's reach
-    count overflows the allocated width the map is rebuilt from scratch.
+    count overflows the allocated width the map is rebuilt from scratch, at
+    a new width with headroom (:func:`flat_width`).
 
     Returns ``(new_map, rebuilt)``. ``ri`` is not mutated.
     """

@@ -41,12 +41,23 @@ Re-association policies
     re-converges from the SAME repaired stable point, but with patched
     slot-index maps and a stale-row-only toggle-cache refresh.
 
+The descent lowers the sum-of-servers surrogate of eq. (17) (each group's
+optimum of problem (18) plus its cloud constant), while a round is charged
+the eq.-(17) cost itself, whose delay term is the slowest server's alone.
+The two can disagree, so a re-association policy deploys its re-solve only
+when that does not raise the round's eq.-(17) cost over the previous
+assignment repaired for the churn (what ``static`` deploys); otherwise the
+repaired assignment is kept (``LiveHistory.kept_frozen``). The descent
+chain itself continues from the re-solve either way: the next warm re-solve
+and the next cold rebuild both start from it.
+
 Every timed solve (round-0, cold, warm) runs with ``finalize=False`` — the
 non-verifying fast path returning just the assignment — so the association
-timers are symmetric across policies: cost accounting happens exactly once
-per round for every policy, on the shared reference-accuracy evaluator
+timers are symmetric across policies: cost accounting runs on the shared
+reference-accuracy evaluator
 (:func:`~repro.core.assoc_fast.assignment_true_cost`), OUTSIDE the
-association timer.
+association timer, once a round, and once more on a re-solve round for
+the repaired previous assignment it is compared with.
 
 Because ``periodic-cold`` descends from exactly the assignment
 ``incremental-warm`` repairs to (both via :func:`repair_assignment`, from
@@ -65,7 +76,8 @@ number of ticks.
 With :mod:`repro.utils.tracing` on, each round writes the host spans
 ``hfel.live.churn`` (the churn tick), ``hfel.live.assoc`` (the policy's
 re-solve or repair) and ``hfel.live.accounting`` (the round's eq.-17
-cost) into the profiler's trace.
+cost, and on a re-solve round that of the repaired previous assignment)
+into the profiler's trace.
 
 Streaming admission under capacities
 ------------------------------------
@@ -146,6 +158,9 @@ class LiveHistory:
     system_delay: list = field(default_factory=list)    # eq. (16)
     assoc_seconds: list = field(default_factory=list)
     swapped: list = field(default_factory=list)
+    # the round's re-solve raised the eq.-17 cost over the repaired
+    # previous assignment, which was deployed instead
+    kept_frozen: list = field(default_factory=list)
     moves: list = field(default_factory=list)
     n_active: list = field(default_factory=list)
     n_arrived: list = field(default_factory=list)
@@ -186,6 +201,7 @@ class LiveHistory:
             "assoc_seconds": [float(s) for s in self.assoc_seconds],
             "assoc_seconds_total": self.assoc_seconds_total,
             "swapped": [bool(s) for s in self.swapped],
+            "kept_frozen": [bool(k) for k in self.kept_frozen],
             "moves": [int(m) for m in self.moves],
             "n_active": [int(a) for a in self.n_active],
             "n_arrived": [int(a) for a in self.n_arrived],
@@ -350,20 +366,47 @@ class LiveHFELRunner:
                 self._admitted[e.devices] = False
                 self._queue.extend(int(d) for d in e.devices)
 
-    def _record(self, *, assoc_s: float, swapped: bool, moves: int,
-                arrived: int, departed: int, admitted: int = 0) -> None:
-        h = self.history
+    def _true_cost(self, assignment: np.ndarray) -> tuple:
         # _eval_solver is None for "proportional" (distance-dependent):
         # assignment_true_cost then builds a fresh per-round solver itself
+        return assignment_true_cost(self.sc, assignment,
+                                    solver=self._eval_solver,
+                                    kind=self.kind, seed=self.seed)
+
+    def _deploy_cheaper(self, previous: np.ndarray) -> tuple:
+        """After a re-solve: keep ``previous`` repaired for this round's
+        churn instead of the re-solve's answer when that is strictly
+        cheaper by eq. (17). Returns the deployed assignment's (E, T, cost)
+        and whether the repaired one was kept. Under caps, a previous
+        assignment the repair cannot place is no candidate."""
         with tracing.span("hfel.live.accounting"):
-            e, t, c = assignment_true_cost(self.sc, self.assignment,
-                                           solver=self._eval_solver,
-                                           kind=self.kind, seed=self.seed)
+            solved = self._true_cost(self.assignment)
+            try:
+                frozen, *_ = repair_assignment(self.sc, previous,
+                                               self._active_prev)
+            except NoFeasibleServerError:
+                return solved, False
+            kept = self._true_cost(frozen)
+        if kept[2] < solved[2]:
+            self.assignment = frozen
+            return kept, True
+        return solved, False
+
+    def _record(self, *, assoc_s: float, swapped: bool, moves: int,
+                arrived: int, departed: int, admitted: int = 0,
+                cost: tuple | None = None, kept_frozen: bool = False
+                ) -> None:
+        h = self.history
+        if cost is None:
+            with tracing.span("hfel.live.accounting"):
+                cost = self._true_cost(self.assignment)
+        e, t, c = cost
         h.system_cost.append(c)
         h.system_energy.append(e)
         h.system_delay.append(t)
         h.assoc_seconds.append(assoc_s)
         h.swapped.append(swapped)
+        h.kept_frozen.append(kept_frozen)
         h.moves.append(moves)
         h.n_active.append(int(self.sc.active_mask.sum()))
         h.n_arrived.append(arrived)
@@ -418,6 +461,8 @@ class LiveHFELRunner:
                 self.sc, delta = churn_tick(self.sc, seed=self.seed, r=r,
                                             churn=self.churn)
         assoc_s, moves, swapped, admitted_n = 0.0, 0, False, 0
+        cost, kept_frozen = None, False
+        previous = self.assignment
         resolve = self.policy != "static" and r % self.resolve_every == 0
         with tracing.span("hfel.live.assoc"):
             if resolve and self.policy == "incremental-warm":
@@ -473,6 +518,7 @@ class LiveHFELRunner:
             self._sc_at_swap = self.sc
             self._active_at_swap = self.sc.active_mask.copy()
             self._assign_at_swap = self.assignment.copy()
+            cost, kept_frozen = self._deploy_cheaper(previous)
         if capped:
             # admission tick every round; on swap rounds this is the
             # post-resolve drain (stable loads just freed by the descent)
@@ -490,10 +536,12 @@ class LiveHFELRunner:
                 jnp.asarray(arrivals_c),
                 jnp.asarray(self.bridge.client_assignment(self.assignment)),
                 self.sc.n_servers)
+        if admitted_n:
+            cost = None     # the drain placed more devices since
         self._record(assoc_s=assoc_s, swapped=swapped, moves=moves,
                      arrived=int(delta.arrived.sum()),
                      departed=int(delta.departed.sum()),
-                     admitted=admitted_n)
+                     admitted=admitted_n, cost=cost, kept_frozen=kept_frozen)
         return self.bridge.client_assignment(self.assignment)
 
 

@@ -78,14 +78,15 @@ def hier_aggregate_tree(trees: list, weights):
 
 
 def golden_section_solve(a, b, d, e, w, f_min, f_max, mask, *,
-                         n_golden: int = 48, n_inner: int = 12,
-                         n_bracket: int = 60, block_g: int | None = None):
-    """Batched fused golden-section RA solve; see
+                         n_golden: int = 24, n_price: int = 5,
+                         n_root: int = 5, n_bracket: int = 24,
+                         block_g: int | None = None):
+    """Batched fused KKT group solve; see
     :mod:`repro.kernels.golden_section` for shapes."""
     return _golden_section_solve(a, b, d, e, w, f_min, f_max, mask,
-                                 n_golden=n_golden, n_inner=n_inner,
-                                 n_bracket=n_bracket, block_g=block_g,
-                                 interpret=not _on_tpu())
+                                 n_golden=n_golden, n_price=n_price,
+                                 n_root=n_root, n_bracket=n_bracket,
+                                 block_g=block_g, interpret=not _on_tpu())
 
 
 def ssd_state_scan(states, decay, initial_state=None):

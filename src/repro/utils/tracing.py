@@ -12,9 +12,14 @@ planes, so a trace shows what the host was doing around every device op::
     ...                                   # engine build, run, finalize
     jax.profiler.stop_trace()
 
+On, each ``span`` also keeps its host duration when it closes; a caller
+that times requests takes them, and clears them, with
+:func:`take_durations` (one call per request).
+
 Off (the default), :func:`span` returns one shared no-op context,
 :func:`ready` returns its argument untouched and :func:`event` does
-nothing, so the hot path pays neither a sync nor an annotation. Spans and
+nothing, so the hot path pays neither a sync, an annotation nor a clock
+read. Spans and
 events belong in host code only: inside a jitted function they would fire
 once, at trace time. Device code carries ``jax.named_scope`` names
 instead, which cost nothing at run time.
@@ -22,12 +27,15 @@ instead, which cost nothing at run time.
 
 from __future__ import annotations
 
-from contextlib import nullcontext
+import time
+from contextlib import contextmanager, nullcontext
 
 import jax
 
 _ON = False
 _OFF = nullcontext()
+# host seconds of each closed span since the last take_durations(), by name
+_DURATIONS: dict[str, list[float]] = {}
 
 
 def enable(on: bool) -> None:
@@ -36,9 +44,26 @@ def enable(on: bool) -> None:
     _ON = bool(on)
 
 
+@contextmanager
+def _timed(name: str):
+    t0 = time.perf_counter()
+    with jax.profiler.TraceAnnotation(name):
+        yield
+    _DURATIONS.setdefault(name, []).append(time.perf_counter() - t0)
+
+
 def span(name: str):
-    """A host span named ``name`` in the profiler's trace."""
-    return jax.profiler.TraceAnnotation(name) if _ON else _OFF
+    """A host span named ``name`` in the profiler's trace, whose duration
+    is kept for :func:`take_durations`."""
+    return _timed(name) if _ON else _OFF
+
+
+def take_durations() -> dict[str, list[float]]:
+    """The host seconds of every span closed since the last call, by span
+    name in closing order; clears them. Empty while tracing is off."""
+    global _DURATIONS
+    out, _DURATIONS = _DURATIONS, {}
+    return out
 
 
 def ready(x):

@@ -64,10 +64,10 @@ def test_golden_kernel_matches_ref():
     cg, masks = _batched_consts(g=6, r=12, seed=2)
     f, beta, cost, dl = ops.golden_section_solve(
         cg.a, cg.b, cg.d, cg.e, cg.w, cg.f_min, cg.f_max, masks,
-        n_golden=16, n_inner=6, n_bracket=24)
+        n_golden=16, n_price=4, n_root=3, n_bracket=16)
     f_r, beta_r, cost_r, dl_r = ref.golden_section_ref(
         cg.a, cg.b, cg.d, cg.e, cg.w, cg.f_min, cg.f_max, masks,
-        n_golden=16, n_inner=6, n_bracket=24)
+        n_golden=16, n_price=4, n_root=3, n_bracket=16)
     np.testing.assert_allclose(cost, cost_r, rtol=1e-6)
     np.testing.assert_allclose(dl, dl_r, rtol=1e-6)
     np.testing.assert_allclose(f, f_r, rtol=1e-6)
@@ -80,10 +80,10 @@ def test_golden_kernel_block_padding():
     cg, masks = _batched_consts(g=5, r=10, seed=3)
     full = ops.golden_section_solve(
         cg.a, cg.b, cg.d, cg.e, cg.w, cg.f_min, cg.f_max, masks,
-        n_golden=16, n_inner=6, n_bracket=24)
+        n_golden=16, n_price=4, n_root=3, n_bracket=16)
     blocked = ops.golden_section_solve(
         cg.a, cg.b, cg.d, cg.e, cg.w, cg.f_min, cg.f_max, masks,
-        n_golden=16, n_inner=6, n_bracket=24, block_g=4)
+        n_golden=16, n_price=4, n_root=3, n_bracket=16, block_g=4)
     for x, y in zip(full, blocked):
         np.testing.assert_allclose(x, y, rtol=1e-6)
 

@@ -336,14 +336,47 @@ def test_live_parity_and_cost_larger_config():
     cold = run_live(sc, ds, policy="periodic-cold", **kw)
     static = run_live(sc, ds, policy="static", **kw)
     assert warm.swap_rounds == cold.swap_rounds
+    assert warm.kept_frozen == cold.kept_frozen
     for aw, ac in zip(warm.swap_assignments, cold.swap_assignments):
         np.testing.assert_array_equal(aw, ac)
     assert abs(warm.cumulative_cost - cold.cumulative_cost) <= (
         1e-6 * cold.cumulative_cost)
     assert warm.cumulative_cost <= static.cumulative_cost * (1 + 1e-9)
     assert cold.cumulative_cost <= static.cumulative_cost * (1 + 1e-9)
+    # the descent lowers what it optimizes, the sum-of-servers form of eq. 17
+    # (each group's optimum of (18) plus its cloud constant), below the
+    # frozen assignment's at every re-solve, whether or not the re-solve is
+    # deployed (a re-solve that raises the max-delay form is not)
+    runners = {p: LiveHFELRunner(sc, sc.n_devices, policy=p,
+                                 resolve_every=2, churn=churn, seed=1)
+               for p in ("incremental-warm", "static")}
+    for r in range(6):
+        for run in runners.values():
+            run.begin_round(_FakeTrainer(), r)
+        if r % 2 or r == 0:
+            continue
+        warm_run, static_run = runners.values()
+        w_cost = _surrogate(warm_run.sc, warm_run.engine.stable_assignment)
+        s_cost = _surrogate(static_run.sc, static_run.assignment)
+        assert w_cost < s_cost * (1 - 1e-3), (r, w_cost, s_cost)
     # training survived the churn: accuracy improved over the run
     assert warm.train.test_acc[-1] > warm.train.test_acc[0]
+
+
+def _surrogate(sc, assignment):
+    """Sum over non-empty groups of the default-profile group cost plus the
+    server's cloud constant."""
+    from repro.core.assoc_fast import _dense_member
+    from repro.core.cost_model import cloud_delay, cloud_energy
+    from repro.core.edge_association import GroupSolver
+
+    member = _dense_member(assignment, sc.active_mask, sc.n_servers)
+    sols = GroupSolver(sc, "fast").solve_batch(np.arange(sc.n_servers),
+                                               member)
+    cloud = np.asarray(sc.lp.lambda_e * cloud_energy(sc.srv)
+                       + sc.lp.lambda_t * cloud_delay(sc.srv))
+    return float(np.sum(np.asarray(sols.cost)
+                        + np.where(member.any(axis=1), cloud, 0.0)))
 
 
 # -- streaming admission under capacities ------------------------------------

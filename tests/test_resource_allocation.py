@@ -5,6 +5,7 @@ the invariants every solver must satisfy plus mutual consistency between
 the paper-faithful solver, the exact solver and the subgradient oracle.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -128,3 +129,92 @@ def test_empty_group_zero_cost():
     c, mask = _instance(0, 0)
     for solver in (ra.solve_exact, ra.solve_fixed_point, ra.solve_paper):
         assert float(solver(c, jnp.zeros(16, bool)).cost) == 0.0
+
+
+# ---------------------------------------------------------------------------
+# The fast kind at the optimum of (18), against an independent reference
+# ---------------------------------------------------------------------------
+
+def _bench_reference():
+    import sys
+    from pathlib import Path
+
+    bench = str(Path(__file__).resolve().parents[1] / "bench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from benchlib import reference
+    return reference
+
+
+# (lambda_t, capacitance scale): the paper's weights with f mostly at f_min,
+# delay-heavy weights, and a capacitance 1000x smaller, which makes
+# computing cheap and sends f to f_max
+REGIMES = [(0.5, 1.0), (0.95, 1.0), (0.5, 1e-3)]
+
+
+def _regime_groups(lambda_t, alpha_scale, seed):
+    """Seeded groups of 2, 3, 4, 12 and 30 members of a 60-device, 5-server
+    scenario, as (scenario, [(server, members)])."""
+    import dataclasses
+
+    lp = LearningParams(lambda_e=1.0 - lambda_t, lambda_t=lambda_t)
+    sc = make_scenario(60, 5, seed=seed, lp=lp)
+    sc = dataclasses.replace(sc, dev=dataclasses.replace(
+        sc.dev, alpha=(np.asarray(sc.dev.alpha) * alpha_scale
+                       ).astype(np.float32)))
+    rng = np.random.default_rng(seed + 100)
+    groups = [(int(rng.integers(5)),
+               np.sort(rng.choice(60, size, replace=False)))
+              for size in (2, 2, 3, 3, 4, 4, 12, 30)]
+    return sc, groups
+
+
+def _batched(sc, groups):
+    from repro.core.cost_model import RAConstants
+
+    cons = [ra_constants(sc.dev, sc.srv.bandwidth[s], sc.srv.noise[s], sc.lp)
+            for s, _ in groups]
+    cb = RAConstants(*(jnp.stack([getattr(c, f) for c in cons])
+                       for f in RAConstants.__dataclass_fields__))
+    masks = np.zeros((len(groups), sc.n_devices), bool)
+    for i, (_, members) in enumerate(groups):
+        masks[i, members] = True
+    return cb, jnp.asarray(masks)
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+@pytest.mark.parametrize("lambda_t,alpha_scale", REGIMES,
+                         ids=["paper", "delay", "cheap-compute"])
+def test_fast_kind_prices_groups_at_the_optimum(lambda_t, alpha_scale,
+                                                backend):
+    """The fast kind's (f, beta), read in float64, costs at most 2.5e-4 (the
+    benchmark's ``ra_gap`` limit) more than the benchmark's NumPy optimum
+    of (18) and than the exact solver's, in every regime: f at f_min, f
+    interior and f at f_max."""
+    reference = _bench_reference()
+    sc, groups = _regime_groups(lambda_t, alpha_scale, seed=4)
+    cb, masks = _batched(sc, groups)
+    fast = ra.solve_fixed_point_batched(cb, masks, backend=backend,
+                                        **ra.SCREEN_PROFILES["default"])
+    exact = jax.vmap(ra.solve_exact)(cb, masks)
+    model = reference.Model(sc)
+
+    def priced(sol):
+        return np.array([reference.group_objective(
+            model, [g], np.asarray(sol.f[i]), np.asarray(sol.beta[i]))[0]
+            for i, g in enumerate(groups)])
+
+    opt = (reference.solve_groups(model, groups)[0]
+           - model.cloud[[s for s, _ in groups]])
+    got = priced(fast)
+    assert np.all((got - opt) / opt <= 2.5e-4), (got - opt) / opt
+    assert np.all((got - priced(exact)) / got <= 2.5e-4)
+    # the regime: some devices clipped at f_max (cheap computing) or at
+    # f_min (the others)
+    m = np.asarray(masks)
+    f = np.asarray(fast.f)[m]
+    if alpha_scale < 1.0:
+        assert (f >= np.asarray(cb.f_max)[m] * (1 - 1e-6)).any()
+    else:
+        assert (f <= np.asarray(cb.f_min)[m] * (1 + 1e-6)).any()
+    assert np.all(np.abs(np.asarray(fast.beta).sum(axis=1) - 1.0) < 1e-5)

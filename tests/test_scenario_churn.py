@@ -136,8 +136,11 @@ def _assert_buckets_consistent(rbk, eff):
 
 
 def test_update_reach_index_patch_and_rebuild():
-    sc = make_scenario(20, 4, seed=3, reach_m=300.0)
+    # sparse enough that the allocated width (the widest count plus
+    # headroom, in whole 128-lane tiles) stays below N
+    sc = make_scenario(400, 8, seed=3, reach_m=150.0)
     ri = reach_index_map(sc.avail)
+    assert ri.widest < ri.r_max < sc.n_devices
     sc2, delta = perturb_scenario(sc, seed=11, **CHURN)
     ri2, rebuilt = update_reach_index(ri, sc2.avail,
                                       active=sc2.active_mask,
@@ -146,6 +149,13 @@ def test_update_reach_index_patch_and_rebuild():
     # shrinking reach never rebuilds (the allocated width is kept) ...
     if not rebuilt:
         assert ri2.r_max == ri.r_max
+    # ... nor does growth inside the headroom ...
+    avail = sc.avail.copy()
+    far = np.flatnonzero(~avail[0])[:ri.r_max - avail[0].sum()]
+    avail[0, far] = True                    # server 0 fills its width
+    ri_full, rebuilt_full = update_reach_index(ri, avail)
+    assert not rebuilt_full and ri_full.r_max == ri.r_max
+    _assert_flat_consistent(ri_full, avail)
     # ... and growth past the allocated width rebuilds from scratch
     avail = sc.avail.copy()
     avail[0, :] = True                      # server 0 now reaches everyone

@@ -77,7 +77,10 @@ def test_reach_index_map_roundtrip(n, k, reach):
           else make_large_scenario(n, k, seed=1))
     ri = reach_index_map(sc.avail)
     counts = sc.avail.sum(axis=1)
-    assert ri.r_max == counts.max()
+    # allocated with headroom: at least an eighth more than the widest
+    # count, in whole 128-lane tiles, never more than N
+    assert ri.widest == counts.max()
+    assert ri.r_max == min(n, 128 * -(-(counts.max() * 9 // 8 + 1) // 128))
     assert 0.0 < ri.density <= 1.0
     for srv in range(k):
         devices = np.flatnonzero(sc.avail[srv])

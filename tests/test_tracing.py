@@ -14,7 +14,8 @@ import pytest
 
 import repro.core.assoc_fast as assoc_fast
 from repro.core import make_scenario
-from repro.core.assoc_fast import COUNT_NAMES, FastAssociationEngine
+from repro.core.assoc_fast import (COUNT_NAMES, RERUN_COUNT_NAMES,
+                                   FastAssociationEngine)
 from repro.core.scenario import make_large_scenario, perturb_scenario
 from repro.fl.live import LiveHFELRunner
 from repro.utils import tracing
@@ -156,8 +157,41 @@ def test_profiler_trace_holds_every_span_nested(tmp_path, traced):
     counts = by_name["hfel.count"]
     assert [_inside(ev, cold) for ev in counts] == [True, False]
     assert counts[0][3] == cold_counts
-    assert counts[1][3] == eng.last_counts
+    assert counts[1][3] == {k: eng.last_counts[k] for k in COUNT_NAMES}
     assert set(cold_counts) == set(COUNT_NAMES)
+    # the warm re-solve's host counts: one event, inside the warm request
+    (rerun,) = by_name["hfel.rerun.count"]
+    assert _inside(rerun, warm)
+    assert rerun[3] == {k: eng.last_counts[k] for k in RERUN_COUNT_NAMES}
+    assert set(eng.last_counts) == set(COUNT_NAMES + RERUN_COUNT_NAMES)
+    assert eng.last_counts["departed"] == int(delta.departed.sum())
+
+
+def test_span_durations_are_taken_per_request():
+    """Tracing on, every closed span keeps its host duration until a caller
+    takes them; off, ``span`` is the one shared no-op and nothing is kept."""
+    tracing.take_durations()
+    _cold_then_warm(False, 0)
+    assert tracing.take_durations() == {}
+    assert tracing.span("hfel.x") is tracing.span("hfel.y")
+    tracing.enable(True)
+    try:
+        eng = _engine(False)
+        eng.run("nearest", exchange_samples=0)
+        cold = tracing.take_durations()
+        sc2, delta = perturb_scenario(eng.sc, seed=1, **CHURN)
+        eng.rerun_incremental(sc2, delta, exchange_samples=0)
+        warm = tracing.take_durations()
+    finally:
+        tracing.enable(False)
+    assert set(cold) >= set(SWEEP_SPANS + ("hfel.finalize",))
+    assert set(warm) >= set(SWEEP_SPANS[1:] + ("hfel.rerun.patch",
+                                                "hfel.rerun.repair"))
+    assert "hfel.rerun.patch" not in cold
+    for spans in (cold, warm):
+        assert all(len(v) >= 1 and min(v) > 0.0 for v in spans.values())
+    assert len(warm["hfel.sweep.init"]) == 1
+    assert tracing.take_durations() == {}
 
 
 class _Trainer:
