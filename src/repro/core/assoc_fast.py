@@ -126,10 +126,13 @@ evaluation seam (tier boundaries re-evaluate the same assignment at the new
 profile's accuracy, so the trace is monotone within tiers, not across them).
 
 The per-group solver is :func:`repro.core.edge_association.solve_group`, so
-every §V.A scheme kind works here; ``profile`` selects a
+every §V.A scheme kind works here. The ``fast`` and ``optimal`` kinds both
+price groups with the KKT solve (``solve_fixed_point``); for the ``fast``
+kind ``profile`` selects a
 :data:`repro.core.resource_allocation.SCREEN_PROFILES` iteration preset
 ("default" reproduces the reference engine bit-for-bit on the solve level,
-"screen"/"coarse" cut sweep cost ~2-4x for large-N scenarios).
+"screen"/"coarse" cut sweep cost ~2-4x for large-N scenarios), while the
+``optimal`` kind always solves at the "default" preset.
 
 Compilation: one cache-init program (:func:`_init_cache`) per (bucket
 shape tuple, ``kind``, ``profile``, cold or warm start) and one move-loop

@@ -71,15 +71,18 @@ def solve_group(kind: str, c: RAConstants, mask, *, random_f=None,
 
     ``c`` holds ONE server's constants; ``mask`` selects the group members.
     ``random_f`` / ``inv_dist_row`` supply the fixed decisions the degenerate
-    §V.A schemes need; ``profile`` picks a :data:`ra.SCREEN_PROFILES` preset
-    for the ``fast`` kind (the others are profile-free).
+    §V.A schemes need. The ``fast`` and ``optimal`` kinds share one solver,
+    the KKT solve :func:`ra.solve_fixed_point`: ``profile`` picks its
+    :data:`ra.SCREEN_PROFILES` preset for the ``fast`` kind, while the
+    ``optimal`` kind always solves at the ``default`` preset (the other
+    kinds are profile-free too).
     """
     n_active = jnp.maximum(jnp.sum(mask), 1)
-    if kind == "fast":
-        return ra.solve_fixed_point(c, mask, **ra.SCREEN_PROFILES[profile])
-    if kind in ("optimal", "paper"):
-        fn = {"optimal": ra.solve_exact, "paper": ra.solve_paper}[kind]
-        return fn(c, mask)
+    if kind in ("fast", "optimal"):
+        preset = profile if kind == "fast" else "default"
+        return ra.solve_fixed_point(c, mask, **ra.SCREEN_PROFILES[preset])
+    if kind == "paper":
+        return ra.solve_paper(c, mask)
     if kind == "comp_only":
         beta = jnp.where(mask, 1.0 / n_active, 0.0)
         return ra.optimize_f_given_beta(c, mask, beta)
@@ -115,8 +118,8 @@ class GroupSolver:
     """Caches per-server RA constants and solves (server, member-mask) groups.
 
     ``kind`` selects the resource-allocation scheme of §V.A:
-      optimal      — solve_exact            (full joint optimization)
-      fast         — solve_fixed_point      (KKT solve, ~1k steps deep)
+      optimal      — solve_fixed_point      (KKT solve at the default preset)
+      fast         — solve_fixed_point      (KKT solve at ``profile``'s preset)
       paper        — solve_paper            (Algorithm 2 faithful)
       comp_only    — optimal f, uniform beta
       comm_only    — optimal beta, random fixed f

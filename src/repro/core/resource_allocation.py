@@ -17,16 +17,16 @@ Four solvers are provided; all are jit-able and vmap-able over padded groups
   f-only convex problem (32) by a projected first-order method with an
   annealed log-sum-exp smoothing of the max (standing in for the paper's
   "CVX / IPOPT").
-* :func:`solve_fixed_point`  — the fast kind: the optimum of (18) from its
-  KKT conditions in every regime (:func:`kkt_rows`): golden section over
-  the common deadline t, Newton on the bandwidth price nu, and per device
-  either the untight f_min case or a Newton root of the tight pair. About
-  a thousand dependent steps; prices the many candidate groups of edge
-  association.
+* :func:`solve_fixed_point`  — the fast and optimal kinds: the optimum of
+  (18) from its KKT conditions in every regime (:func:`kkt_rows`): golden
+  section over the common deadline t, Newton on the bandwidth price nu,
+  and per device either the untight f_min case or a Newton root of the
+  tight pair. About a thousand dependent steps; prices the many candidate
+  groups of edge association and the reported final costs.
 * :func:`solve_exact`        — exact nested parametric solver: golden-section
   over the deadline t, bisection over the bandwidth multiplier nu, per-device
   golden-section for the (convex) boundary trade-off. Handles all box/cap
-  clipping cases; the reported final costs use this.
+  clipping cases; ~81k dependent steps, the test oracle.
 * :func:`solve_reference`    — plain projected subgradient on (f, beta)
   jointly. Slow, structure-free; the test oracle.
 """
@@ -137,7 +137,7 @@ def solve_paper(c: RAConstants, mask: jnp.ndarray, *, n_steps: int = 400) -> RAS
 
 
 # ---------------------------------------------------------------------------
-# Solver 2 — KKT solve (the fast kind)
+# Solver 2 — KKT solve (the fast and optimal kinds)
 # ---------------------------------------------------------------------------
 
 def _deadline_bracket(c: RAConstants, mask, n_bracket: int = 60):
@@ -221,9 +221,10 @@ def resolve_tiers(tiers) -> tuple[str, ...]:
 def solve_fixed_point(c: RAConstants, mask: jnp.ndarray, *, n_golden: int = 24,
                       n_price: int = 5, n_root: int = 5,
                       n_bracket: int = 24) -> RASolution:
-    """The fast kind's group solve: the optimum of (18) from its KKT
-    conditions (:func:`kkt_rows`), at a dependent depth of about a
-    thousand steps where :func:`solve_exact` takes ~81k.
+    """The group solve of the fast and optimal kinds: the optimum of (18)
+    from its KKT conditions (:func:`kkt_rows`), at a dependent depth of
+    about a thousand steps where :func:`solve_exact` takes ~81k. The
+    optimal kind always solves at the ``default`` preset.
 
     ``(n_golden, n_price, n_root, n_bracket)`` presets live in
     :data:`SCREEN_PROFILES`.

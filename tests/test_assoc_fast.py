@@ -1,10 +1,13 @@
 """Device-resident association engine: parity with the host reference,
 permission semantics, and toggle-cache consistency."""
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import make_scenario
+from repro.core import resource_allocation as ra
 from repro.core.assoc_fast import FastAssociationEngine
 from repro.core.edge_association import AssociationEngine
 from repro.core.scenario import make_large_scenario
@@ -139,6 +142,35 @@ def test_identity_and_slot_maps_move_for_move_vs_reference(compact):
     assert np.array_equal(fast.assignment, ref.assignment)
     np.testing.assert_allclose(np.asarray(fast.cost_trace),
                                np.asarray(ref.cost_trace), rtol=1e-4)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_optimal_kind_move_for_move_vs_reference_at_exact_cost(seed):
+    """The optimal kind replicates the host reference engine's moves on a
+    small dense scenario, and at the paper's point (N=60, K=5) its
+    finalized ``total_cost`` equals the exact solver's pricing of the same
+    stable assignment within 1e-5 relative."""
+    sc = make_scenario(14, 3, seed=seed)
+    ref = AssociationEngine(sc, kind="optimal", seed=0).run_batched(
+        "nearest", exchange_samples=0)
+    fast = FastAssociationEngine(sc, kind="optimal", seed=0,
+                                 compact=False).run("nearest",
+                                                    exchange_samples=0)
+    assert fast.n_adjustments == ref.n_adjustments
+    assert np.array_equal(fast.assignment, ref.assignment)
+    np.testing.assert_allclose(np.asarray(fast.cost_trace),
+                               np.asarray(ref.cost_trace), rtol=1e-4)
+
+    sc = make_scenario(60, 5, seed=seed)
+    eng = FastAssociationEngine(sc, kind="optimal", seed=0)
+    res = eng.run("nearest")
+    member = np.zeros((5, 60), bool)
+    member[res.assignment, np.arange(60)] = True
+    exact = jax.vmap(ra.solve_exact)(eng.solver.consts, jnp.asarray(member))
+    total = float(np.sum(np.asarray(exact.cost, np.float64)
+                         + np.where(member.any(axis=1),
+                                    np.asarray(eng.cloud_const), 0.0)))
+    assert abs(res.total_cost - total) <= 1e-5 * total
 
 
 def test_large_scenario_generator_shapes():

@@ -182,7 +182,7 @@ def test_pallas_backend_engine_matches_xla():
 # stable point, so the escape path genuinely fires). In the dense and flat
 # spaces the single-device engine refreshes both touched rows in one
 # batched solve and the sharded one row by row, so these cases also hold
-# the fused refresh to the unfused one, for the exact solver too.
+# the fused refresh to the unfused one, for the optimal kind too.
 EXCHANGE_MATRIX = ([(c, p, s, "fast")
                     for c in ("bucketed", True, False)
                     for p in (1, 3, 4)

@@ -218,3 +218,52 @@ def test_fast_kind_prices_groups_at_the_optimum(lambda_t, alpha_scale,
     else:
         assert (f <= np.asarray(cb.f_min)[m] * (1 + 1e-6)).any()
     assert np.all(np.abs(np.asarray(fast.beta).sum(axis=1) - 1.0) < 1e-5)
+
+
+# ---------------------------------------------------------------------------
+# The optimal kind: the KKT solve at the default preset, against solve_exact
+# ---------------------------------------------------------------------------
+
+PAPER_SIZES = (1, 2, 5, 15, 30, 45, 60)
+
+
+def _paper_groups(size):
+    """One seeded group of ``size`` members on each server of the paper's
+    point (N=60, K=5), as (scenario, [(server, members)])."""
+    sc = make_scenario(60, 5, seed=0)
+    rng = np.random.default_rng(size)
+    return sc, [(s, np.sort(rng.choice(60, size, replace=False)))
+                for s in range(5)]
+
+
+@pytest.mark.parametrize(
+    "case", [*PAPER_SIZES, *REGIMES],
+    ids=[f"size-{n}" for n in PAPER_SIZES] + ["paper", "delay",
+                                               "cheap-compute"])
+def test_optimal_kind_prices_groups_at_the_exact_solvers_cost(case):
+    """``solve_group("optimal")`` costs at most 1e-6 relative more than
+    ``solve_exact`` on the paper point's groups of 1-60 members and in
+    every regime (f at f_min, interior, at f_max), with the shares summing
+    to 1 and f in its box; the kind ignores the screening profile."""
+    from repro.core.edge_association import GroupSolver, solve_group
+
+    sc, groups = (_paper_groups(case) if isinstance(case, int)
+                  else _regime_groups(*case, seed=4))
+    cb, masks = _batched(sc, groups)
+    got = jax.vmap(lambda c, m: solve_group("optimal", c, m))(cb, masks)
+    exact = jax.vmap(ra.solve_exact)(cb, masks)
+    cost = np.asarray(got.cost, np.float64)
+    oracle = np.asarray(exact.cost, np.float64)
+    assert np.all((cost - oracle) / oracle <= 1e-6), (cost - oracle) / oracle
+    m = np.asarray(masks)
+    assert np.all(np.abs(np.asarray(got.beta).sum(axis=1) - 1.0) <= 2e-5)
+    f = np.asarray(got.f)[m]
+    assert np.all(f >= np.asarray(cb.f_min)[m])
+    assert np.all(f <= np.asarray(cb.f_max)[m])
+    servers = np.array([s for s, _ in groups])
+    default = GroupSolver(sc, "optimal", profile="default").solve_batch(
+        servers, m)
+    coarse = GroupSolver(sc, "optimal", profile="coarse").solve_batch(
+        servers, m)
+    for a, b in zip(jax.tree.leaves(default), jax.tree.leaves(coarse)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
